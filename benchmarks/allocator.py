@@ -34,32 +34,13 @@ from repro.core.allocator import (
     _per_link_rates,
     _per_link_rates_vmap,
     allocate,
+    random_problem,
 )
-from repro.core.flowstate import FlowState
 
 N_FLOWS = 1_000
 LINK_SIZES = (100, 1_000, 10_000)
 SMOKE = os.environ.get("REPRO_SMOKE", "").strip() not in ("", "0")
 DT = 5.0
-
-
-def _mk_problem(L: int, F: int = N_FLOWS, seed: int = 0,
-                links_per_flow: int = 4) -> tuple[LinkProgram, FlowState]:
-    """Sparse random program: each flow crosses ~``links_per_flow`` links;
-    kinds split uplink/downlink/internal like a fat-tree."""
-    rng = np.random.default_rng(seed)
-    R = np.zeros((F, L), np.float32)
-    for f in range(F):
-        R[f, rng.choice(L, size=min(links_per_flow, L), replace=False)] = 1.0
-    kind = rng.choice([0, 1, 2], size=L, p=[0.4, 0.4, 0.2]).astype(np.int32)
-    prog = LinkProgram(
-        R=jnp.asarray(R),
-        capacity=jnp.asarray(rng.uniform(1.0, 50.0, L), jnp.float32),
-        kind=jnp.asarray(kind),
-    )
-    st = FlowState(*[jnp.asarray(rng.uniform(0, 10, F), jnp.float32)
-                     for _ in range(5)])
-    return prog, st
 
 
 @functools.partial(jax.jit, static_argnames=("dt",))
@@ -76,7 +57,7 @@ def run() -> list[dict]:
     rows = []
     sizes = [s for s in LINK_SIZES if not (SMOKE and s > 1_000)]
     for L in sizes:
-        prog, st = _mk_problem(L)
+        prog, st = random_problem(L, N_FLOWS)
         iters = max(2, min(10, 20_000 // L))
 
         us_sort = timeit_us(

@@ -25,9 +25,11 @@ the overhead the packing amortizes measured and tracked across PRs, and
 gives the planner's `TICK_OVERHEAD_FLOPS` calibration (see
 `repro.streams.fleet`) a checked-in measurement trail.
 
-On CPU the scenario axis is additionally split across forced XLA host
-devices (one per core, up to 8) via the runner's plain-SPMD sharding —
-set BEFORE jax initializes, hence the env fiddling above the imports.
+With ``JAX_PLATFORMS=cpu`` the scenario axis is additionally split
+across forced XLA host devices (one per core, up to 8) via the runner's
+plain-SPMD sharding — set BEFORE jax initializes, hence the env fiddling
+above the imports. On an accelerator the runner shards over its real
+devices.
 
     PYTHONPATH=src python benchmarks/fleet.py
 """
@@ -37,7 +39,10 @@ import os
 import sys
 import time
 
-if "jax" not in sys.modules:  # too late to force devices otherwise
+# host devices only exist to be forced on the CPU platform, and only
+# before jax initializes
+if ("jax" not in sys.modules
+        and os.environ.get("JAX_PLATFORMS", "").strip() == "cpu"):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={min(os.cpu_count() or 1, 8)}"
@@ -501,77 +506,40 @@ def run_campaign_resilience(policy: str = "tcp", n: int = 256,
 
 def run_campaign_scaling(policy: str = "tcp", n: int = 256,
                          seconds: float = SECONDS) -> list[dict]:
-    """Sharded chunk stream at 4 emulated devices vs 1 device.
-
-    The 4-device half runs in a subprocess (device count is baked in at
-    jax import). On this 1-core container 4 emulated devices share one
-    core, so the gateable number is a *not-much-worse* bound — sharding
-    must not serialize or duplicate work (wall within the floor of the
-    1-device run), while real scaling is a wide-backend claim (ROADMAP
-    item 2). Metrics parity at 4 devices is asserted bitwise in
+    """Sharded chunk stream over this process's devices vs one device,
+    both timed in this process (no child: on an accelerator the parent
+    already holds the devices). With forced host devices on one CPU core
+    the gateable number is a *not-much-worse* bound — sharding must not
+    serialize or duplicate work; real scaling is a chip measurement.
+    Metrics parity across device counts is asserted bitwise in
     tests/test_multidevice.py; this row tracks the wall-clock."""
-    import json as _json
-    import subprocess
-
     sims = compile_fleet(campaign_fleet(n, seed=0))
     runner = FleetRunner()
-
-    def stream():
-        return runner.run_campaign(sims, policy, seconds=seconds, dt=DT,
-                                   shard=False)
-    stream()  # compile
-    t_1dev, _ = _wall_median(stream, max(2, WARM_REPS - 2))
-
-    child = (
-        "import json,sys,time,numpy as np\n"
-        "from repro.streams import campaign_fleet, compile_fleet\n"
-        "from repro.streams.fleet import FleetRunner\n"
-        "import jax\n"
-        f"sims = compile_fleet(campaign_fleet({n}, seed=0))\n"
-        "r = FleetRunner()\n"
-        f"call = lambda: r.run_campaign(sims, {policy!r}, "
-        f"seconds={seconds}, dt={DT})\n"
-        "call()\n"
-        "ts = []\n"
-        f"for _ in range({max(2, WARM_REPS - 2)}):\n"
-        "    t0 = time.time(); call(); ts.append(time.time() - t0)\n"
-        "st = r.last_stats\n"
-        "print('SCALING ' + json.dumps({\n"
-        "    'warm_s': float(np.median(ts)),\n"
-        "    'n_streams': st['n_streams'],\n"
-        "    'n_devices': jax.local_device_count(),\n"
-        "    'transfer_overlap': st['transfer_overlap'],\n"
-        "    'overlap_fraction': st['overlap_fraction']}))\n")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = (
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "src")
-        + os.pathsep + env.get("PYTHONPATH", ""))
-    out = subprocess.run([sys.executable, "-c", child], env=env,
-                         capture_output=True, text=True, timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(f"4-device scaling child failed:\n{out.stderr}")
-    payload = _json.loads(
-        next(line for line in out.stdout.splitlines()
-             if line.startswith("SCALING ")).split(" ", 1)[1])
-    t_4dev = float(payload["warm_s"])
+    walls, stats = {}, {}
+    for shard in (False, True):
+        def stream():
+            return runner.run_campaign(sims, policy, seconds=seconds,
+                                       dt=DT, shard=shard)
+        stream()  # compile
+        walls[shard], _ = _wall_median(stream, max(2, WARM_REPS - 2))
+        stats[shard] = dict(runner.last_stats)
+    st = stats[True]
     return [{
         "name": "fleet_campaign_scaling",
-        "us_per_call": t_4dev * 1e6,
+        "us_per_call": walls[True] * 1e6,
         "n_scenarios": n,
         "backend": jax.default_backend(),
         "host_cores": os.cpu_count(),
-        "n_devices": payload["n_devices"],
-        "n_streams_4dev": payload["n_streams"],
-        "warm_1dev_s": round(t_1dev, 3),
-        "warm_4dev_s": round(t_4dev, 3),
-        # >= floor: emulated sharding on a shared core must stay within
-        # a constant factor of the single-stream run (not serialize or
-        # duplicate work); > 1 means real parallel win (multi-core)
-        "scaling_efficiency_4dev": round(t_1dev / t_4dev, 3),
-        "transfer_overlap_4dev": round(payload["transfer_overlap"], 3),
-        "overlap_fraction_4dev": round(payload["overlap_fraction"], 3),
+        "n_devices": jax.local_device_count(),
+        "n_streams": st["n_streams"],
+        "warm_1dev_s": round(walls[False], 3),
+        "warm_ndev_s": round(walls[True], 3),
+        # >= floor: sharding on shared host cores must stay within a
+        # constant factor of the single-stream run (not serialize or
+        # duplicate work); > 1 means a real parallel win
+        "scaling_efficiency": round(walls[False] / walls[True], 3),
+        "transfer_overlap_ndev": round(st["transfer_overlap"], 3),
+        "overlap_fraction_ndev": round(st["overlap_fraction"], 3),
     }]
 
 

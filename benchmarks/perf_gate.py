@@ -32,12 +32,11 @@ checks, per policy:
   (``guard_overhead`` ≤ the ceiling; and the fault-free A/B must report
   zero retries/quarantines — anything else means the guards misfire
   without faults), and
-* the ``fleet_campaign_scaling`` row — the 4-emulated-device sharded
-  chunk stream must stay within a constant factor of the 1-device run
-  (``scaling_efficiency_4dev``; on the 1-core CI container the four
-  streams share one core, so the floor only catches sharding that
-  serializes or duplicates work — real scaling is the wide-backend
-  ROADMAP item).
+* the ``fleet_campaign_scaling`` row — the chunk stream sharded over
+  the forced host devices must stay within a constant factor of the
+  1-device run (``scaling_efficiency``; on a small CI container the
+  streams share its cores, so the floor only catches sharding that
+  serializes or duplicates work — real scaling is a chip measurement).
 
 Missing input files are a hard, *loud* failure: benchmark snapshots are
 checked into the repo (see ``.gitignore`` history — they used to be
@@ -114,8 +113,8 @@ REROUTE_FULL_CEIL = 1.5
 RESILIENCE_SMOKE_CEIL = 1.25
 RESILIENCE_FULL_CEIL = 1.05
 
-# 4-emulated-device scaling floors (t_1dev / t_4dev): on a 1-core
-# container the four streams share the core, so anything >= ~0.6 means
+# forced-host-device scaling floors (t_1dev / t_ndev): on a 1-core
+# container the streams share the core, so anything >= ~0.6 means
 # the shard neither serialized nor duplicated work; multi-core targets
 # (> 1) belong to the wide-backend ROADMAP item, not this gate.
 SCALING_SMOKE_FLOOR = 0.5
@@ -273,8 +272,8 @@ def check(path: str) -> int:
                 f"retries/quarantines "
                 f"({rs.get('n_retries')}/{rs.get('n_quarantined')}) — the "
                 f"guards are misfiring without faults")
-    # sharded chunk stream at 4 emulated devices: within a constant
-    # factor of the 1-device run
+    # sharded chunk stream over the forced host devices: within a
+    # constant factor of the 1-device run
     sc = by_name.get("fleet_campaign_scaling")
     sfloor = SCALING_SMOKE_FLOOR if smoke else SCALING_FULL_FLOOR
     if sc is None:
@@ -282,22 +281,22 @@ def check(path: str) -> int:
         table.append(("fleet_campaign_scaling", "missing", f"{sfloor:.2f}",
                       "-", "MISSING"))
     else:
-        eff = float(sc.get("scaling_efficiency_4dev", 0.0))
+        eff = float(sc.get("scaling_efficiency", 0.0))
         ndev = sc.get("n_devices")
         ok_eff = eff >= sfloor
-        ok_dev = ndev == 4
+        ok_dev = (ndev or 0) > 1
         status = "ok" if (ok_eff and ok_dev) else "REGRESSED"
         table.append(("fleet_campaign_scaling", f"{eff:.2f}",
                       f"{sfloor:.2f}", f"{ndev} dev", status))
         if not ok_eff:
             failures.append(
-                f"fleet_campaign_scaling: scaling_efficiency_4dev "
+                f"fleet_campaign_scaling: scaling_efficiency "
                 f"{eff:.2f} < floor {sfloor:.2f} (sharded stream "
                 f"serialized or duplicated work)")
         if not ok_dev:
             failures.append(
-                f"fleet_campaign_scaling: measured on {ndev} devices, "
-                f"expected 4 — the forced-device child lost its XLA flag")
+                f"fleet_campaign_scaling: measured on {ndev} device(s) — "
+                f"the host-device XLA flag was not applied")
     # companion snapshots exist (content is informational — calibration
     # rows — but absence means the bench job dropped a section)
     bench_dir = os.path.dirname(os.path.abspath(path)) or "."

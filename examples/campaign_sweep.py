@@ -29,6 +29,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.streams import FleetRunner, campaign_fleet, compile_fleet
 
 N = 2048
@@ -37,6 +38,7 @@ POLICY = "tcp"
 
 
 def main() -> None:
+    setup_compile_cache()
     scenarios = campaign_fleet(N, seed=0)
     sims = compile_fleet(scenarios)
     runner = FleetRunner()

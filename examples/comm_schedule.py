@@ -11,6 +11,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.core.scheduler import extract_flows, plan_schedule
 from repro.launch.mesh import _mk
 from repro.launch.shardings import batch_shardings, opt_shardings, param_shardings
@@ -21,6 +22,7 @@ from repro.train.step import make_train_step
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     args = ap.parse_args()

@@ -12,6 +12,7 @@ never ask).
 """
 from __future__ import annotations
 
+from repro.compile_cache import setup_compile_cache
 from repro.net import big_switch, link_failure_schedule
 from repro.streams import (
     compile_sim,
@@ -26,6 +27,7 @@ SECONDS = 120.0
 
 
 def main() -> None:
+    setup_compile_cache()
     g = parallelize(trending_topics(), seed=0)
     topo = big_switch(8, 1.25)
     sched = link_failure_schedule(topo, [0, 1, 2, 3], t_fail=T_FAIL,

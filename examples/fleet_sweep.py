@@ -17,12 +17,14 @@ from __future__ import annotations
 
 import time
 
+from repro.compile_cache import setup_compile_cache
 from repro.streams import FleetRunner, capacity_sweep, compile_fleet
 
 SECONDS = 600.0
 
 
 def main() -> None:
+    setup_compile_cache()
     scenarios = capacity_sweep(multihop=False) + capacity_sweep(multihop=True)
     sims = compile_fleet(scenarios)
     runner = FleetRunner()

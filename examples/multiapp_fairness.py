@@ -8,10 +8,12 @@ apps (paper: Jain 0.84 -> 0.98+).
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.core import AppFairScheduler, jain_index, maxmin_rates
 
 
 def main() -> None:
+    setup_compile_cache()
     n_apps = 5
     app_of_flow = np.concatenate([[a] * (a + 1) for a in range(n_apps)])
     F = len(app_of_flow)

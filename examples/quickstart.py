@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.data.pipeline import SyntheticLM
 from repro.models.registry import get_config, get_model
 from repro.net import big_switch
@@ -53,5 +54,6 @@ def lm_demo():
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     stream_demo()
     lm_demo()

@@ -9,11 +9,13 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.models.registry import get_config, get_model
 from repro.serve.engine import Request, ServeEngine
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
     ap.add_argument("--requests", type=int, default=6)

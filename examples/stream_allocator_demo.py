@@ -3,6 +3,7 @@ settings, TCP vs App-aware — the core result of the paper in one script.
 
     PYTHONPATH=src python examples/stream_allocator_demo.py
 """
+from repro.compile_cache import setup_compile_cache
 from repro.net import LinkKind, big_switch, fat_tree
 from repro.streams import (
     compile_sim,
@@ -17,6 +18,7 @@ CAPS = {"10Mbps": 1.25, "15Mbps": 1.875, "20Mbps": 2.5}
 
 
 def main() -> None:
+    setup_compile_cache()
     for setting, topo_fn in (
         ("single-hop (up/downlink bottleneck)", lambda c: big_switch(8, c)),
         ("multi-hop (fat-tree, throttled internals)",

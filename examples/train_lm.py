@@ -16,6 +16,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.data.pipeline import SyntheticLM
 from repro.models.registry import get_config, get_model
 from repro.train.driver import DriverConfig, TrainDriver
@@ -30,6 +31,7 @@ PRESETS = {
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--preset", default="small", choices=list(PRESETS))

@@ -34,12 +34,17 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.flowstate import FlowState
 from repro.net.topology import LinkKind
 
 _EPS = 1e-9
 _INF = jnp.inf
+
+# f32 contractions run at HIGHEST: the chip's default is one bf16 pass,
+# which rounds the rates, demands and queue volumes these products carry
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 # Trace-time auto-chunk threshold for the sort solver's link axis: above
 # 2x this many links, `allocate(block_links=None)` switches to
@@ -115,6 +120,29 @@ class LinkProgram(NamedTuple):
     R: jnp.ndarray          # [F, L] binary routing matrix
     capacity: jnp.ndarray   # [L]
     kind: jnp.ndarray       # [L] LinkKind values
+
+
+def random_problem(n_links: int, n_flows: int = 1_000, seed: int = 0,
+                   links_per_flow: int = 4) -> tuple[LinkProgram, FlowState]:
+    """Seeded controller instance at fabric scale: each flow crosses
+    ``links_per_flow`` random links, link kinds split 40/40/20
+    uplink/downlink/internal like a fat-tree, capacities U(1, 50) MB/s and
+    every FlowState field U(0, 10)."""
+    rng = np.random.default_rng(seed)
+    R = np.zeros((n_flows, n_links), np.float32)
+    for f in range(n_flows):
+        R[f, rng.choice(n_links, size=min(links_per_flow, n_links),
+                        replace=False)] = 1.0
+    kind = rng.choice([0, 1, 2], size=n_links,
+                      p=[0.4, 0.4, 0.2]).astype(np.int32)
+    prog = LinkProgram(
+        R=jnp.asarray(R),
+        capacity=jnp.asarray(rng.uniform(1.0, 50.0, n_links), jnp.float32),
+        kind=jnp.asarray(kind),
+    )
+    st = FlowState(*[jnp.asarray(rng.uniform(0, 10, n_flows), jnp.float32)
+                     for _ in range(5)])
+    return prog, st
 
 
 def _per_link_rates_vmap(program: LinkProgram, state: FlowState, dt: float):
@@ -282,7 +310,7 @@ def backfill(x: jnp.ndarray, program: LinkProgram, iters: int = 8,
     on_net = jnp.sum(R, axis=1) > 0  # flows that traverse ≥1 link
 
     def body(_, x):
-        load = x @ R                                   # [L]
+        load = jnp.matmul(x, R, precision=_HIGHEST)    # [L]
         ratio = jnp.maximum(cap - load, 0.0) / jnp.maximum(load, _EPS)
         r_min = jnp.min(jnp.where(on_link, ratio[None, :], _INF), axis=1)
         inc = jnp.where(on_net & jnp.isfinite(r_min), x * r_min, 0.0)
@@ -340,7 +368,7 @@ def allocate(
     x = jnp.where(jnp.isfinite(x), x, 0.0)     # flows with no links: handled by caller
 
     # Internal links: proportional scale-down, min across links (lines 24-29)
-    load = x @ program.R                                       # [L]
+    load = jnp.matmul(x, program.R, precision=_HIGHEST)        # [L]
     is_int = kind == int(LinkKind.INTERNAL)
     scale_l = jnp.where(
         is_int & (load > program.capacity),

@@ -70,6 +70,10 @@ import numpy as np
 _EPS = 1e-9
 _INF = jnp.inf
 
+# f32 contractions run at HIGHEST: the chip's default is one bf16 pass,
+# which rounds the rates, demands and queue volumes these products carry
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 # Trip count of the hot-path fused fill. Each round freezes EVERY locally
 # minimal bottleneck level in parallel, so rounds + 1 (the closing sweep
 # resolves one further level) must cover the depth of the strictly-
@@ -234,7 +238,8 @@ def demand_limited_maxmin(R, capacity, demand, iters: int | None = None):
         newf = jnp.where(jnp.any(sated), sated, at_lvl)
         vals = jnp.minimum(d, th_flow)           # th_flow = inf → demand
         x = jnp.where(newf, vals, x)
-        resid = jnp.maximum(resid - jnp.where(newf, vals, 0.0) @ R, 0.0)
+        resid = jnp.maximum(resid - jnp.matmul(
+            jnp.where(newf, vals, 0.0), R, precision=_HIGHEST), 0.0)
         return x, frozen | newf, resid, jnp.any(newf), rounds + 1
 
     x0 = jnp.where(on_net, 0.0, jnp.asarray(demand, jnp.float32))
@@ -315,7 +320,8 @@ def _link_levels(A1, d, m, resid):
     is the routing mask restricted to unfrozen flows. Returns θ [L].
     """
     F, L = m.shape
-    P = A1 @ jnp.concatenate([m, d[:, None] * m], axis=1)     # [F+1, 2L]
+    P = jnp.matmul(A1, jnp.concatenate([m, d[:, None] * m], axis=1),
+                   precision=_HIGHEST)                        # [F+1, 2L]
     return _theta_from_parts(m, P[F, :L], P[F, L:], P[:F, :L], P[:F, L:],
                              resid)
 
@@ -332,7 +338,7 @@ def _link_levels_blocked(A1, d, m, resid, block_flows: int):
     path — it activates only above ``2 * MAXMIN_BLOCK_FLOWS`` flows)."""
     F, L = m.shape
     rhs = jnp.concatenate([m, d[:, None] * m], axis=1)        # [F, 2L]
-    tot = A1[F] @ rhs                                         # [2L]
+    tot = jnp.matmul(A1[F], rhs, precision=_HIGHEST)          # [2L]
     n_l, sum_d = tot[:L], tot[L:]
     blk = max(int(block_flows), 1)
     nb = -(-F // blk)
@@ -343,7 +349,7 @@ def _link_levels_blocked(A1, d, m, resid, block_flows: int):
 
     def chunk(args):
         Ac, mc = args                       # [blk, F], [blk, L]
-        Pc = Ac @ rhs                       # [blk, 2L]
+        Pc = jnp.matmul(Ac, rhs, precision=_HIGHEST)  # [blk, 2L]
         denom = n_l[None, :] - Pc[:, :L]
         theta_k = (resid[None, :] - Pc[:, L:]) / jnp.maximum(denom, 0.5)
         cand = jnp.where((mc > 0) & (denom > 0.5), theta_k, -_INF)
@@ -405,8 +411,8 @@ def _fill(R, on_net, d, levels, capacity, rounds: int):
         newf = hit | sated
         vals = jnp.minimum(d, th_flow)        # th_flow=inf → demand
         x = jnp.where(newf, vals, x)
-        resid = jnp.maximum(
-            resid - jnp.where(newf, vals, 0.0) @ R, 0.0)
+        resid = jnp.maximum(resid - jnp.matmul(
+            jnp.where(newf, vals, 0.0), R, precision=_HIGHEST), 0.0)
         return x, frozen | newf, resid
 
     carry = (jnp.zeros((R.shape[0],), jnp.float32), ~on_net,
@@ -622,3 +628,42 @@ def demand_limited_maxmin_np(R, capacity, demand):
         resid = np.maximum(resid - (vals * newf) @ R, 0.0)
         frozen |= newf
     return x
+
+
+def assert_maxmin_certificate(R, cap, d, x, tol: float = 1e-4) -> None:
+    """KKT certificate of demand-limited max-min optimality, raising
+    ``AssertionError`` on the first violation:
+
+      * feasible: no link is oversubscribed and 0 ≤ x_f ≤ d_f;
+      * off-net flows get exactly their demand (unconstrained);
+      * every on-net flow is either demand-capped, or crosses a saturated
+        link where no flow has a greater rate (its bottleneck).
+    """
+    R = np.asarray(R, np.float64)
+    cap = np.asarray(cap, np.float64)
+    d = np.asarray(d, np.float64)
+    x = np.asarray(x, np.float64)
+    load = x @ R
+    scale = max(float(cap.max(initial=1.0)), 1.0)
+    if not np.all(load <= cap + tol * scale):
+        raise AssertionError(f"link oversubscribed by {(load - cap).max()}")
+    if not np.all(x >= -tol):
+        raise AssertionError(f"negative rate {x.min()}")
+    on_net = R.sum(1) > 0
+    np.testing.assert_allclose(x[~on_net], d[~on_net], atol=tol)
+    if not np.all(x[on_net] <= d[on_net]
+                  + tol * np.maximum(d[on_net], 1.0)):
+        raise AssertionError("rate above demand")
+    saturated = load >= cap - tol * np.maximum(cap, 1.0)
+    for f in np.nonzero(on_net)[0]:
+        if x[f] >= d[f] - tol * max(d[f], 1.0):
+            continue  # demand-capped
+        links = np.nonzero((R[f] > 0) & saturated)[0]
+        if not links.size:
+            raise AssertionError(
+                f"flow {f}: below demand but no saturated link")
+        # bottleneck: some saturated link where f's rate is maximal
+        if not any(x[f] >= x[R[:, link] > 0].max() - tol * max(1.0, x.max())
+                   for link in links):
+            raise AssertionError(
+                f"flow {f}: rate {x[f]} not maximal on any saturated link")

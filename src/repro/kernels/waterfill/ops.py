@@ -1,8 +1,10 @@
 """Jitted wrapper for the waterfill kernel: padding, backend selection.
 
-On TPU the Pallas kernel runs compiled; on CPU (this container) it runs in
-``interpret=True`` mode, which executes the kernel body per-program in
-Python — bit-identical control flow, validated against ``ref.py``.
+On TPU the Pallas kernel runs compiled; on CPU (the test platform) it runs
+in ``interpret=True`` mode, which executes the kernel body per-program in
+Python — bit-identical control flow, validated against ``ref.py``. Any
+other platform is an error: the kernel is never silently interpreted on
+an accelerator.
 
 Padding happens *inside* one jitted function whose pad targets are static
 arguments derived from the input shapes, so repeat calls at the same shape
@@ -61,7 +63,12 @@ def _waterfill_padded(weights, backlog, rho, mask, capacity, kind, *,
 def _dispatch(weights, backlog, rho, mask, capacity, kind, dt, block_links,
               block_flows, interpret):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"waterfill kernel runs compiled on tpu or interpreted on "
+                f"cpu; default backend is {backend!r}")
+        interpret = backend == "cpu"
     if block_flows is not None:
         assert block_flows % 128 == 0, block_flows
     L, F = mask.shape

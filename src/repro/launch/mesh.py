@@ -13,11 +13,8 @@ import jax
 
 
 def _mk(shape, axes):
-    # AxisType landed after jax 0.4.x; Auto is the default there anyway
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = {} if axis_type is None else {
-        "axis_types": (axis_type.Auto,) * len(axes)}
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -179,8 +179,11 @@ TICK_OVERHEAD_FLOPS_CPU = 15e3
 # *where* inside the band we land but never flip a plan-structure
 # invariant. Unknown (wide) backends get a far looser band: per-op
 # overhead there is genuinely orders of magnitude larger relative to a
-# single scenario's FLOPs.
-_CALIB_CLAMP = {"cpu": (8e3, 64e3)}
+# single scenario's FLOPs. The "tpu" band brackets the two raw products
+# measured on one TPU v5e chip (187 with the per-tick probe at its
+# 0.05 us floor, and 510): there the tick term is below the ~0.6 ms
+# dispatch noise it is differenced against.
+_CALIB_CLAMP = {"cpu": (8e3, 64e3), "tpu": (1.5e2, 1e3)}
 _CALIB_CLAMP_DEFAULT = (5e2, 1e6)
 
 
@@ -1174,6 +1177,7 @@ class FleetRunner:
             "n_dispatches": n_dispatches,
             "n_buckets": len(plan),
             "n_scenarios": len(sims),
+            "n_shards": n_shards,
             "rows": row_counts,
             "bucket_shapes": [dataclasses.astuple(s) for _, s in plan],
             "policy": policy,
@@ -1362,6 +1366,7 @@ class FleetRunner:
         failures: list[FailureRecord] = []
         n_retries = n_recovered = n_dispatched = 0
         chunks_done = 0
+        chunks_on: dict[str, int] = {}  # device -> pipeline chunks run there
         rec_col = metric_index("recovery_time_s")
 
         # ---- checkpoint/resume ----
@@ -1437,6 +1442,8 @@ class FleetRunner:
             for b, i in enumerate(idxs):
                 if bad is None or not bad[b]:
                     metrics_all[i] = m[b]
+            for d in outs[6].devices():
+                chunks_on[str(d)] = chunks_on.get(str(d), 0) + 1
             if results is not None:
                 host = [np.asarray(o) for o in outs[:6]]
                 for b, i in enumerate(idxs):
@@ -1565,6 +1572,8 @@ class FleetRunner:
                 try:
                     m, host = _run_subset_once(bi, j, idxs, chunk, s)
                     return m, host, None, attempt + 1
+                except jax.errors.JaxRuntimeError:
+                    raise  # device or compile fault: not the scenario's
                 except Exception as e:  # noqa: BLE001 — retried
                     err = e
             return None, None, err, max_retries + 1
@@ -1621,8 +1630,15 @@ class FleetRunner:
             """Chunk-level failure path: whole-chunk retries with backoff;
             retries exhausted (or surviving non-finite rows) bisect down
             to the scenarios responsible. Never raises — the campaign
-            completes with quarantined rows instead of dying."""
+            completes with quarantined rows instead of dying — except on
+            a ``JaxRuntimeError`` (compile error, device fault, exhausted
+            HBM), which propagates: the scenarios are not at fault."""
             nonlocal n_recovered
+            if isinstance(first_error, jax.errors.JaxRuntimeError):
+                # a compile error or an exhausted device surfaces as-is
+                # instead of being retried and bisected into quarantined
+                # rows that would read as bad scenarios
+                raise first_error
             n_recovered += 1
             m, host, err, _ = _try_subset(bi, j, idxs, chunk,
                                           j % n_streams)
@@ -1775,6 +1791,7 @@ class FleetRunner:
                 "n_buckets": len(plan),
                 "n_scenarios": len(sims),
                 "n_streams": n_streams,
+                "chunks_per_device": chunks_on,
                 "rows": cap_rows,
                 "chunk_rows": max(cap_rows),
                 "target_chunk_rows": target_rows,

@@ -56,6 +56,10 @@ _EPS = 1e-9
 INTERNAL_RATE = 1e6  # MB/s: same-machine flows move at memory speed
 _LAT_CAP = 1e4       # s: cap on per-flow latency contribution (stalled flows)
 
+# f32 contractions run at HIGHEST: the chip's default is one bf16 pass,
+# which rounds the rates, demands and queue volumes these products carry
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 # The campaign summary vector computed by the in-program metric epilogue
 # (`_metrics_epilogue`), in order. Throughput entries are MB-based (the
 # per-scenario ``tuples_per_mb`` conversion is one exact scalar multiply,
@@ -428,7 +432,7 @@ def _metrics_epilogue(sink, wait, load, caps_grid, path_w, dt: float,
     T = sink.shape[0]
     warm = T // 4
     rate = sink / dt                                           # [T] MB/s
-    lat_t = wait @ path_w                                      # [T]
+    lat_t = jnp.matmul(wait, path_w, precision=_HIGHEST)       # [T]
     # bottleneck utilization per SimResult.bottleneck_utilization: mean
     # per-tick utilization against the *scheduled* capacity, averaged over
     # links carrying >= hot_thresh of capacity (all-cold fallback: the
@@ -443,8 +447,10 @@ def _metrics_epilogue(sink, wait, load, caps_grid, path_w, dt: float,
     # count so the trace boundaries don't fake a dip)
     w = max(int(round(win_s / dt)), 1)
     kern = jnp.ones((w,), rate.dtype)
-    r = (jnp.convolve(rate, kern, mode="same")
-         / jnp.convolve(jnp.ones_like(rate), kern, mode="same"))
+    # per-tick sample count of the window: a constant of the static shape
+    count = np.convolve(np.ones(T), np.ones(w), mode="same")
+    r = (jnp.convolve(rate, kern, mode="same", precision=_HIGHEST)
+         / count.astype(np.float32))
     i = min(int(round(t_event / dt)), T - 1)                   # static
     pre_mean = r[max(0, i - int(round(pre_s / dt))):max(i, 1)].mean()
     post = r[i:]
@@ -525,7 +531,7 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
         # updates a failed/shrunk link moves at most caps_t·dt, whatever
         # the stale rate vector says. Feasible loads scale by exactly 1.0,
         # so a constant schedule reproduces the static path.
-        load0 = desired @ R                                      # [L] MB
+        load0 = jnp.matmul(desired, R, precision=_HIGHEST)       # [L] MB
         lscale = jnp.where(load0 > caps_t * dt,
                            jnp.clip(caps_t * dt / jnp.maximum(load0, _EPS),
                                     0.0, 1.0),
@@ -549,7 +555,7 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
     join_amt = jnp.minimum(join_amt, sim.proc_rate * dt)
     consume_join = join_amt[dst] * sim.p_in                      # [F]
 
-    total_in = sim.M_in @ Qr                                     # [I]
+    total_in = jnp.matmul(sim.M_in, Qr, precision=_HIGHEST)      # [I]
     amt = jnp.minimum(total_in, sim.proc_rate * dt)
     frac = amt / jnp.maximum(total_in, _EPS)
     consume_any = Qr * frac[dst]
@@ -559,7 +565,7 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
 
     # sender-side backpressure (Storm's bounded send buffers): an instance
     # whose outgoing queue is full stalls its processing / generation
-    in_i = sim.M_in @ consume                                    # [I]
+    in_i = jnp.matmul(sim.M_in, consume, precision=_HIGHEST)     # [I]
     out_i = sim.selectivity * in_i + sim.gen_rate * dt
     prod = out_i[src] * sim.w_of_flow                            # [F]
     space = jnp.maximum(qcap - Qs, 0.0)
@@ -592,7 +598,7 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
         # fleet vmap this is a batched GEMM where a scatter would serialize
         onehot = (sim.app_of_inst[None, :]
                   == jnp.arange(sim.n_apps)[:, None]).astype(sink_in.dtype)
-        sink_mb_app = onehot @ sink_in
+        sink_mb_app = jnp.matmul(onehot, sink_in, precision=_HIGHEST)
     drain = consume / dt                                         # [F] MB/s
 
     # --- latency estimate (per source→sink path) ----------------------
@@ -603,7 +609,8 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
         Qs / jnp.maximum(x, _EPS) + Qr / jnp.maximum(drain, _EPS), _LAT_CAP
     )
 
-    link_load = transfer @ R / dt                                # [L] MB/s
+    link_load = jnp.matmul(transfer, R,
+                           precision=_HIGHEST) / dt              # [L] MB/s
     return Qs, Qr, transfer, drain, (sink_mb, sink_mb_app, wait, link_load)
 
 
@@ -869,7 +876,7 @@ def _run(sim: CompiledSim, policy: str, n_ticks: int, dt: float,
         # per-policy carry pieces are gated *statically*: a policy that
         # never reads prod_rate/B/mu_acc doesn't pay their per-tick ops
         if policy == "tcp":
-            t_in = sim.M_in @ transfer
+            t_in = jnp.matmul(sim.M_in, transfer, precision=_HIGHEST)
             out_i = sim.selectivity * t_in + sim.gen_rate * dt
             prod_rate = out_i[sim.src_of_flow] * sim.w_of_flow / dt
             drain_ewma = 0.5 * drain_ewma + 0.5 * drain

@@ -19,6 +19,8 @@ from repro.core.allocator import (
     LinkProgram,
     _per_link_rates,
     _per_link_rates_vmap,
+    allocate,
+    random_problem,
 )
 from repro.net import big_switch, fat_tree, LinkKind
 
@@ -338,6 +340,23 @@ class TestAlgorithm1:
             # and the pallas path alone stays feasible
             load = xp @ topo.routing_matrix(flows)
             assert np.all(load <= topo.capacities * (1 + 1e-3))
+
+    def test_random_problem_seeded_and_feasible(self):
+        # the fabric-scale controller instance, cut to a CPU size: seeded,
+        # 4 links per flow, and both solvers agree and stay feasible
+        prog, st_ = random_problem(300, 64, seed=7)
+        again, st2 = random_problem(300, 64, seed=7)
+        np.testing.assert_array_equal(prog.R, again.R)
+        np.testing.assert_array_equal(st_.lr_t1, st2.lr_t1)
+        R = np.asarray(prog.R)
+        assert R.shape == (64, 300) and np.all(R.sum(1) == 4)
+        assert set(np.unique(np.asarray(prog.kind))) <= {0, 1, 2}
+        xs = np.asarray(allocate(prog, st_, dt=5.0, solver="sort"))
+        xp = np.asarray(allocate(prog, st_, dt=5.0, solver="pallas"))
+        np.testing.assert_allclose(xs, xp, rtol=2e-3, atol=2e-3)
+        cap = np.asarray(prog.capacity)
+        for x in (xs, xp):
+            assert np.all(x @ R <= cap * (1 + 1e-3))
 
     def test_unknown_solver_rejected(self):
         topo = big_switch(2, 10.0)

@@ -112,8 +112,6 @@ _SUBPROC = textwrap.dedent("""\
         st = hlo_stats.collective_stats(c.as_text())
         out["train_collectives"] = st["count"]
         ca = c.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax<=0.4.x returns [dict]
-            ca = ca[0] if ca else {{}}
         out["train_flops"] = float(ca.get("flops", 0))
         # decode
         dspec = ShapeSpec("d", 64, 8, "decode")

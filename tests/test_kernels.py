@@ -121,6 +121,15 @@ class TestWaterfill:
                                  block_flows=block_flows))
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
+    def test_interprets_only_on_cpu(self, monkeypatch):
+        # compiled on tpu, interpreted on cpu, refused anywhere else: the
+        # kernel is never silently interpreted on an accelerator
+        a = np.ones((4, 16), np.float32)
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            waterfill(a, a, a, a, np.ones(4, np.float32),
+                      np.zeros(4, np.int32))
+
     def test_padding_is_jit_cached(self):
         # repeat same-shape calls reuse the padded executable (the pad ops
         # trace once; no per-call un-jitted jnp.pad dispatch chain)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
 from repro.core.tcp import (
+    assert_maxmin_certificate,
     demand_limited_maxmin,
     demand_limited_maxmin_np,
     maxmin_fused,
@@ -55,36 +56,6 @@ def _instance(seed: int, F: int, L: int, links_per_flow: int,
     if zero_demand:
         d[rng.integers(0, F)] = 0.0
     return R, cap, d
-
-
-def _assert_maxmin_invariant(R, cap, d, x, tol=1e-4):
-    """KKT certificate of demand-limited max-min optimality:
-
-      * feasible: no link is oversubscribed and 0 ≤ x_f ≤ d_f;
-      * off-net flows get exactly their demand (unconstrained);
-      * every on-net flow is either demand-capped, or crosses a saturated
-        link where no flow has a greater rate (its bottleneck).
-    """
-    x = np.asarray(x, np.float64)
-    load = x @ R
-    scale = max(float(cap.max(initial=1.0)), 1.0)
-    assert np.all(load <= cap + tol * scale), (load - cap).max()
-    assert np.all(x >= -tol)
-    on_net = R.sum(1) > 0
-    np.testing.assert_allclose(x[~on_net], d[~on_net], atol=tol)
-    assert np.all(x[on_net] <= d[on_net] + tol * np.maximum(d[on_net], 1.0))
-    saturated = load >= cap - tol * np.maximum(cap, 1.0)
-    for f in np.nonzero(on_net)[0]:
-        if x[f] >= d[f] - tol * max(d[f], 1.0):
-            continue  # demand-capped
-        links = np.nonzero((R[f] > 0) & saturated)[0]
-        assert links.size, f"flow {f}: below demand but no saturated link"
-        # bottleneck: some saturated link where f's rate is maximal
-        ok = any(
-            x[f] >= x[R[:, link] > 0].max() - tol * max(1.0, x.max())
-            for link in links
-        )
-        assert ok, f"flow {f}: rate {x[f]} not maximal on any saturated link"
 
 
 def _fused(R, cap, d, rounds="default"):
@@ -127,7 +98,7 @@ class TestFusedParity:
         oracle = np.asarray(demand_limited_maxmin(
             jnp.asarray(R), jnp.asarray(cap), jnp.asarray(d)))
         np.testing.assert_allclose(got, oracle, atol=ATOL * 10, rtol=1e-5)
-        _assert_maxmin_invariant(R, cap, d, oracle)
+        assert_maxmin_certificate(R, cap, d, oracle)
 
     def test_seed_5041_oracle_is_maxmin(self):
         # regression pin for the clamp-and-resolve defect: flow 15's
@@ -140,7 +111,7 @@ class TestFusedParity:
         oracle = np.asarray(demand_limited_maxmin(
             jnp.asarray(R), jnp.asarray(cap), jnp.asarray(d)))
         np.testing.assert_allclose(oracle, ref, atol=ATOL * 10, rtol=1e-5)
-        _assert_maxmin_invariant(R, cap, d, oracle)
+        assert_maxmin_certificate(R, cap, d, oracle)
 
     @settings(max_examples=40, deadline=None)
     @given(seed=st.integers(0, 10_000),
@@ -153,7 +124,7 @@ class TestFusedParity:
         R, cap, d = _instance(seed, F, L, links_per_flow,
                               zero_cap, zero_demand, off_net)
         x = _fused(R, cap, d, rounds=None)
-        _assert_maxmin_invariant(R, cap, d, x)
+        assert_maxmin_certificate(R, cap, d, x)
 
     @settings(max_examples=25, deadline=None)
     @given(seed=st.integers(0, 10_000))
@@ -166,6 +137,21 @@ class TestFusedParity:
         assert np.all(load <= cap + 1e-4 * np.maximum(cap, 1.0))
         on_net = R.sum(1) > 0
         assert np.all(x[on_net] <= d[on_net] + 1e-4)
+
+
+class TestCertificate:
+    @pytest.mark.parametrize("bump", ["oversubscribe", "not_bottleneck"])
+    def test_rejects_non_maxmin(self, bump):
+        # two flows share one link of capacity 2, demands 5: max-min is
+        # (1, 1); the certificate must reject anything else
+        R = np.ones((2, 1), np.float32)
+        cap = np.array([2.0], np.float32)
+        d = np.array([5.0, 5.0], np.float32)
+        assert_maxmin_certificate(R, cap, d, np.array([1.0, 1.0]))
+        x = (np.array([1.5, 1.0]) if bump == "oversubscribe"
+             else np.array([1.5, 0.5]))
+        with pytest.raises(AssertionError):
+            assert_maxmin_certificate(R, cap, d, x)
 
 
 class TestEdgeCases:
@@ -243,7 +229,7 @@ class TestCorpusRounds:
                 got = _fused(R, cap, d, rounds=FILL_ROUNDS)
                 np.testing.assert_allclose(got, exact, atol=ATOL,
                                            rtol=1e-5)
-                _assert_maxmin_invariant(R, cap, d, exact)
+                assert_maxmin_certificate(R, cap, d, exact)
 
     def test_policy_path_parity_with_while_oracle(self):
         """End-to-end: 40 ticks of the tcp per-tick loop (`_tick` + demand

@@ -314,6 +314,27 @@ class TestTeardown:
         assert stats["n_chunks_done"] == stats["n_chunks"]
         assert stats["n_dispatches"] == stats["n_chunks"]
         assert stats["n_retries"] == 0 == stats["n_quarantined"]
+        assert sum(stats["chunks_per_device"].values()) == stats["n_chunks"]
+
+    def test_device_error_propagates_unquarantined(self, runner, corpus,
+                                                   oracle, monkeypatch):
+        # a compile error or exhausted device is not the scenarios' fault:
+        # it surfaces as-is instead of being retried into quarantined rows
+        import jax
+
+        def exhausted(*a, **kw):
+            raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: injected")
+
+        with monkeypatch.context() as mp:
+            mp.setattr(jax, "device_put", exhausted)
+            with pytest.raises(jax.errors.JaxRuntimeError,
+                               match="RESOURCE_EXHAUSTED"):
+                _campaign(runner, corpus)
+        stats = runner.last_stats
+        assert stats["status"] == "failed"
+        assert stats["n_retries"] == 0 == stats["n_quarantined"]
+        np.testing.assert_array_equal(_campaign(runner, corpus).metrics,
+                                      oracle)
 
 
 class TestInputValidation:
