@@ -5,12 +5,8 @@ import json
 import os
 import time
 
-from repro.compile_cache import setup_compile_cache
 from repro.net import LinkKind, big_switch, fat_tree
 from repro.streams import compile_sim, parallelize, round_robin, simulate
-
-# every benchmark imports this module before its first compile
-setup_compile_cache()
 
 CAPS = {"10Mbps": 1.25, "15Mbps": 1.875, "20Mbps": 2.5}
 SECONDS = 600.0

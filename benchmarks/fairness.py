@@ -7,6 +7,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from benchmarks.common import emit
+from repro.compile_cache import setup_compile_cache
 from repro.core import AppFairScheduler, jain_index, maxmin_rates
 
 
@@ -53,4 +54,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     main()

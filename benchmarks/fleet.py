@@ -25,11 +25,11 @@ the overhead the packing amortizes measured and tracked across PRs, and
 gives the planner's `TICK_OVERHEAD_FLOPS` calibration (see
 `repro.streams.fleet`) a checked-in measurement trail.
 
-With ``JAX_PLATFORMS=cpu`` the scenario axis is additionally split
-across forced XLA host devices (one per core, up to 8) via the runner's
-plain-SPMD sharding — set BEFORE jax initializes, hence the env fiddling
-above the imports. On an accelerator the runner shards over its real
-devices.
+With ``JAX_PLATFORMS=cpu`` the process forces XLA host devices (one per
+core, at least 4 and at most 8) — set BEFORE jax initializes, hence the
+env fiddling above the imports — so the sharded paths have devices to
+spread over: `run` places whole buckets on them, `run_campaign` its
+chunks. On an accelerator the runner uses its real devices.
 
     PYTHONPATH=src python benchmarks/fleet.py
 """
@@ -45,13 +45,14 @@ if ("jax" not in sys.modules
         and os.environ.get("JAX_PLATFORMS", "").strip() == "cpu"):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={min(os.cpu_count() or 1, 8)}"
-    )
+        + " --xla_force_host_platform_device_count="
+        + str(max(4, min(os.cpu_count() or 1, 8))))
 
 import jax
 import numpy as np
 
 from benchmarks.common import emit
+from repro.compile_cache import setup_compile_cache
 from repro.streams import (
     FleetRunner,
     bench_fleet,
@@ -132,6 +133,7 @@ def run(policy: str = "appaware", seconds: float = SECONDS) -> list[dict]:
         "speedup_warm": round(t_seq_warm / t_bat_warm, 2),
         "warm_ms_per_scenario": round(t_bat_warm * 1e3 / len(sims), 3),
         "n_dispatches": stats["n_dispatches"],
+        "n_shards": stats["n_shards"],
         "n_buckets": stats["n_buckets"],
         "max_tps_diff": f"{worst:.2e}",
         # tcp only: demand-order cache rebuilds across the whole fleet
@@ -204,7 +206,7 @@ def run_dispatch_floor(seconds: float = SECONDS) -> list[dict]:
     def timed(runner):
         def call():
             return runner.run(sims, "fixed", seconds=seconds, dt=DT,
-                              x_fixed=xf)
+                              x_fixed=xf, shard=False)
         call()  # compile
         t, _ = _wall_median(call, WARM_REPS)
         return t, runner.last_stats
@@ -559,4 +561,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     main()

@@ -6,6 +6,7 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import DT, emit
+from repro.compile_cache import setup_compile_cache
 from repro.net import big_switch
 from repro.streams import compile_sim, motivation_chain, parallelize, simulate
 
@@ -59,4 +60,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     main()

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit, smoke_mode, timeit_us
+from repro.compile_cache import setup_compile_cache
 from repro.core import FlowState, OnlineAllocator, maxmin_rates
 from repro.kernels.waterfill.ops import waterfill
 from repro.net import fat_tree
@@ -88,4 +89,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     main()

@@ -4,10 +4,12 @@ Parses ``BENCH_fleet.json`` (written by ``benchmarks/fleet.py``) and
 checks, per policy:
 
 * ``speedup_warm`` against a checked-in floor,
-* ``n_dispatches == 1`` — the packed runtime's structural invariant: a
-  warm fleet run is ONE fused executable. A solver or runner change that
-  silently falls back to per-bucket dispatch fails the gate even if the
-  wall-clock happens to look fine on the runner that day, and
+* ``n_dispatches == n_shards`` — the packed runtime's structural
+  invariant: a warm fleet run is ONE fused executable per device it uses
+  (``n_shards``, 1 unless the run spreads its buckets over forced host
+  devices). A solver or runner change that silently falls back to
+  per-bucket dispatch fails the gate even if the wall-clock happens to
+  look fine on the runner that day, and
 * the ``fleet_order_cache`` row — the order-cached max-min solver must
   rebuild its demand-rank operand exactly ONCE per scenario on the
   static-demand corpus scan (the tick-0 cold start). More than one means
@@ -159,8 +161,9 @@ def check(path: str) -> int:
             continue
         got = float(row.get("speedup_warm", 0.0))
         disp = row.get("n_dispatches")
+        n_sh = row.get("n_shards", 1)
         ok_speed = got >= floor
-        ok_disp = disp == 1
+        ok_disp = disp == n_sh
         status = "ok" if (ok_speed and ok_disp) else "REGRESSED"
         table.append((name, f"{got:.2f}", f"{floor:.2f}",
                       f"{disp}", status))
@@ -169,8 +172,8 @@ def check(path: str) -> int:
                 f"{name}: speedup_warm {got:.2f} < floor {floor:.2f}")
         if not ok_disp:
             failures.append(
-                f"{name}: n_dispatches {disp} != 1 (packed runtime "
-                f"fell back to per-bucket dispatch)")
+                f"{name}: n_dispatches {disp} != {n_sh} devices used "
+                f"(packed runtime fell back to per-bucket dispatch)")
     # order-cache structural invariant: exactly one rebuild per scenario
     # on the static-demand corpus scan
     oc = by_name.get("fleet_order_cache")

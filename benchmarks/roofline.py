@@ -6,6 +6,7 @@ import json
 import pathlib
 
 from benchmarks.common import emit
+from repro.compile_cache import setup_compile_cache
 from repro.launch.roofline import cell_terms
 
 RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results" / "dryrun"
@@ -51,4 +52,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     main()

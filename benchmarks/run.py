@@ -5,6 +5,8 @@ from __future__ import annotations
 import sys
 import traceback
 
+from repro.compile_cache import setup_compile_cache
+
 
 def main() -> None:
     from benchmarks import (
@@ -42,4 +44,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     main()

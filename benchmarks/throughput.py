@@ -10,6 +10,7 @@ from benchmarks.common import (
     run_pair,
     singlehop_topo,
 )
+from repro.compile_cache import setup_compile_cache
 from repro.streams import trending_topics, trucking_iot
 
 
@@ -35,4 +36,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     main()

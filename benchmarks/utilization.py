@@ -4,6 +4,7 @@ keeps it work-conserving."""
 from __future__ import annotations
 
 from benchmarks.common import CAPS, emit, run_pair, singlehop_topo
+from repro.compile_cache import setup_compile_cache
 from repro.streams import trending_topics, trucking_iot
 
 
@@ -25,4 +26,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     main()

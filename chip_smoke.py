@@ -16,13 +16,19 @@ One chip, in one process:
 3. numerics: 16 sampled scenarios per policy re-run with ``simulate`` on
    the host CPU device of the same process, against the chip's campaign
    rows; tcp max-min solves on the chip against the numpy progressive
-   fill and its KKT certificate.
+   fill and its KKT certificate;
+4. row count: ``bench_fleet()`` through ``FleetRunner.run`` against
+   ``run_campaign`` with each bucket in one chunk of the same rows
+   (bitwise: a bucket's program does not depend on the other buckets of
+   the run, which is what the sharded ``run`` relies on) and with chunks
+   of at most 3 rows (within the reference tolerances; how far from
+   bitwise a different batch row count lands is reported).
 
 ``--chips 4`` runs only the sharded paths and their one-chip references:
-``run_campaign(shard=True)`` against ``shard=False`` (bitwise) and
-``FleetRunner.run(shard=True)`` against unsharded on ``bench_fleet()``
-(metric rows within the CPU-reference tolerances; the distance from
-bitwise is reported per trajectory field).
+``run_campaign(shard=True)`` against ``shard=False`` and
+``FleetRunner.run(shard=True)`` against unsharded on ``bench_fleet()``:
+metrics bitwise, trajectories bitwise, and the run's ``total_sink_mb``
+within a few ULP.
 
 Data comes from ``--seed``. Wall times are printed as informational. The
 last line of stdout is ``{"ok": true, "device": {...}}`` and is printed
@@ -39,6 +45,7 @@ import os
 import sys
 import time
 import traceback
+import zlib
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
@@ -56,6 +63,7 @@ N_SAMPLE = 16
 RTOL = 1e-3          # throughput, latency, utilization, sink volume
 DIP_ATOL = 0.05
 RECOVERY_ATOL_S = 3.0
+SINK_ULPS = 4        # sharded run's total_sink_mb against unsharded
 
 
 class Checks:
@@ -122,7 +130,8 @@ def _median_s(fn, reps: int = 5) -> float:
 def phase_controller(c: Checks, seed: int) -> None:
     import jax
     import numpy as np
-    from repro.core.allocator import allocate, random_problem
+    from benchmarks.allocator import random_problem
+    from repro.core.allocator import allocate
 
     dt = 5.0
     prog, st = random_problem(10_000, 1_000, seed=seed, links_per_flow=4)
@@ -169,11 +178,16 @@ def phase_campaign(c: Checks, seed: int, sims, out: dict) -> None:
         keys = ("n_chunks", "n_buckets", "rows", "n_streams",
                 "chunks_per_device", "stage_s", "transfer_s",
                 "dispatch_s", "block_s", "wall_s")
+        m = cr.metrics
+        # digest of the metric matrix: equal digests in two processes
+        # mean the campaign reproduced bit for bit
+        crc = zlib.crc32(np.ascontiguousarray(m).tobytes())
         c.report[f"campaign_{policy}"] = {k: st[k] for k in keys}
+        c.report[f"campaign_{policy}"]["metrics_crc32"] = crc
         print(f"  {policy}: {len(sims)} scenarios, {st['n_chunks']} chunks "
               f"over {st['n_buckets']} buckets (rows {st['rows']}), "
-              f"{wall:.3f} s wall (chip, informational)")
-        m = cr.metrics
+              f"metrics crc32 {crc:08x}, {wall:.3f} s wall "
+              f"(chip, informational)")
         others = np.delete(m, rec, axis=1)
         c.check(not cr.failures and st["n_retries"] == 0
                 and st["n_quarantined"] == 0,
@@ -282,7 +296,11 @@ def phase_numerics(c: Checks, seed: int, sims, campaigns: dict) -> None:
         c.check(ok, f"maxmin_fused {name} passes the KKT certificate{why}")
 
 
-# ------------------------------------------------------------ 4 chips
+# ------------------------------------------------------------- phase 4
+TRAJ_FIELDS = ("sink_mb", "sink_mb_app", "latency", "link_load", "caps_t",
+               "order_rebuilds")
+
+
 def _ulps(a, b):
     import numpy as np
     ai = np.asarray(a, np.float32).view(np.int32).astype(np.int64)
@@ -290,6 +308,62 @@ def _ulps(a, b):
     return np.abs(ai - bi)
 
 
+def _compare_runs(ref, got) -> tuple[dict, list[str]]:
+    """Per field: [scenarios differing, max ULP]; and the scenarios whose
+    metric rows miss the reference tolerances."""
+    import numpy as np
+    diff: dict[str, list] = {}
+    bad = []
+    for i, (r0, r1) in enumerate(zip(ref, got)):
+        for f in TRAJ_FIELDS + ("metrics",):
+            x, y = getattr(r0, f), getattr(r1, f)
+            if x is None or np.array_equal(x, y):
+                continue
+            d = diff.setdefault(f, [0, 0])
+            d[0] += 1
+            if f != "order_rebuilds":
+                fin = np.isfinite(x) & np.isfinite(y)
+                d[1] = max(d[1], int(_ulps(x[fin], y[fin]).max(initial=0)))
+        m = _row_mismatches(got[i].metrics, r0.metrics)
+        if m:
+            bad.append(f"scenario {i}: " + "; ".join(m))
+    return diff, bad
+
+
+def phase_row_count(c: Checks, seed: int) -> None:
+    from repro.streams import FleetRunner, bench_fleet, compile_fleet
+
+    sims = compile_fleet(bench_fleet(seed=seed))
+    for policy in ("tcp", "appaware"):
+        runner = FleetRunner()
+        ref = runner.run(sims, policy, seconds=SECONDS, shard=False)
+        rows = runner.last_stats["rows"]
+        biggest = max(len(idxs) for idxs, _ in runner.plan(sims, policy))
+        for label, chunk in (("whole buckets", biggest),
+                             ("chunks of <= 3 rows", 3)):
+            cr = runner.run_campaign(sims, policy, seconds=SECONDS,
+                                     chunk_rows=chunk, shard=False,
+                                     retain_trajectories=True)
+            diff, bad = _compare_runs(ref, cr.results)
+            chunk_rows = runner.last_stats["rows"]
+            c.report[f"row_count_{policy}_{chunk}"] = {
+                "run_rows": rows, "chunk_rows": chunk_rows,
+                "differing": diff, "mismatches": bad}
+            print(f"  {policy}, run rows {rows} vs campaign {label} "
+                  f"(rows {chunk_rows}): differing field: "
+                  f"[scenarios, max ULP] {diff}")
+            for w in bad[:4]:
+                print(f"    {w}")
+            if chunk == biggest:
+                c.check(not diff, f"{policy}: each bucket as one campaign "
+                        f"chunk is bitwise equal to run")
+            else:
+                c.check(not bad, f"{policy}: smaller chunks match run within "
+                        f"the reference tolerances "
+                        f"({len(sims) - len(bad)}/{len(sims)})")
+
+
+# ------------------------------------------------------------ 4 chips
 def phase_sharded_campaign(c: Checks, seed: int) -> None:
     import numpy as np
     from repro.streams import FleetRunner, campaign_fleet, compile_fleet
@@ -321,12 +395,13 @@ def phase_sharded_campaign(c: Checks, seed: int) -> None:
 
 
 def phase_sharded_run(c: Checks, seed: int) -> None:
-    """SPMD-sharded ``run`` against unsharded: the metric rows are held to
-    the CPU-reference tolerances; how far from bitwise the trajectories
-    are is reported per field."""
-    import numpy as np
+    """Sharded ``run`` (whole buckets spread over the chips) against
+    unsharded: trajectories bitwise, metrics bitwise but for
+    ``total_sink_mb`` within ``SINK_ULPS``."""
     from repro.streams import FleetRunner, bench_fleet, compile_fleet
+    from repro.streams.simulator import CAMPAIGN_METRICS, metric_index
 
+    sink = metric_index("total_sink_mb")
     sims = compile_fleet(bench_fleet(seed=seed))
     for policy in ("tcp", "appaware"):
         outs = {}
@@ -334,39 +409,31 @@ def phase_sharded_run(c: Checks, seed: int) -> None:
             runner = FleetRunner()
             outs[shard] = runner.run(sims, policy, seconds=SECONDS,
                                      shard=shard)
+            st = runner.last_stats
             if shard:
-                c.check(runner.last_stats["n_shards"] == 4,
-                        f"{policy}: run sharded over "
-                        f"{runner.last_stats['n_shards']} devices")
-        diff: dict[str, list] = {}
-        bad = []
-        for i, (r0, r1) in enumerate(zip(outs[False], outs[True])):
-            for f in ("sink_mb", "sink_mb_app", "latency", "link_load",
-                      "caps_t", "order_rebuilds", "metrics"):
-                x, y = getattr(r0, f), getattr(r1, f)
-                if x is None or np.array_equal(x, y):
-                    continue
-                d = diff.setdefault(f, [0, 0, 0.0])
-                d[0] += 1
-                if f != "order_rebuilds":
-                    fin = np.isfinite(x) & np.isfinite(y)
-                    d[1] = max(d[1], int(_ulps(x[fin], y[fin]).max(
-                        initial=0)))
-                    d[2] = max(d[2], float(np.max(
-                        np.abs(x[fin] - y[fin])
-                        / np.maximum(np.abs(x[fin]), 1e-30), initial=0)))
-            m = _row_mismatches(r1.metrics, r0.metrics)
-            if m:
-                bad.append(f"scenario {i}: " + "; ".join(m))
-        c.report[f"run_shard_{policy}"] = {"differing": diff,
-                                           "mismatches": bad}
-        print(f"  {policy}: bitwise {'yes' if not diff else 'no'}; "
-              f"differing field: [scenarios, max ULP, max rel] {diff}")
-        for w in bad[:4]:
-            print(f"    {w}")
-        c.check(not bad, f"{policy}: sharded run metrics match unsharded "
-                f"within the reference tolerances "
-                f"({len(sims) - len(bad)}/{len(sims)})")
+                n_used = min(4, st["n_buckets"])
+                print(f"  {policy}: bucket rows {st['rows']} on devices "
+                      f"{st['bucket_devices']}")
+                c.check(st["n_shards"] == n_used
+                        and len(set(st["bucket_devices"])) == n_used,
+                        f"{policy}: {st['n_buckets']} buckets spread over "
+                        f"{st['n_shards']} devices")
+        diff, _ = _compare_runs(outs[False], outs[True])
+        loose = {f: d for f, d in diff.items() if f != "metrics"}
+        c.report[f"run_shard_{policy}"] = {"differing": diff}
+        print(f"  {policy}: differing field: [scenarios, max ULP] {diff}")
+        c.check(not loose, f"{policy}: sharded run trajectories bitwise "
+                f"equal to unsharded")
+        worst = 0
+        cols = set()
+        for r0, r1 in zip(outs[False], outs[True]):
+            u = _ulps(r0.metrics, r1.metrics)
+            cols |= {CAMPAIGN_METRICS[k] for k in u.nonzero()[0]}
+            worst = max(worst, int(u[sink]))
+        c.check(cols <= {"total_sink_mb"} and worst <= SINK_ULPS,
+                f"{policy}: sharded run metrics bitwise but total_sink_mb, "
+                f"which is within {worst} <= {SINK_ULPS} ULP "
+                f"(columns differing: {sorted(cols)})")
 
 
 # --------------------------------------------------------------- main
@@ -417,6 +484,7 @@ def main() -> int:
                     campaigns)
         else:
             c.failed.append("3 numerics: skipped, campaign incomplete")
+        c.phase("4 row count", phase_row_count, args.seed)
     c.report["compile_cache"] = cache_seen
     print(f"compile cache {cache}: {cache_seen['lookups']} lookups, "
           f"{cache_seen['hits']} hits, {cache_seen['writes']} written, "

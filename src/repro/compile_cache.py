@@ -1,8 +1,9 @@
 """JAX persistent compilation cache for the program's entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/common.py``, the examples)
-call :func:`setup_compile_cache` once before their first compile; the
-library never calls it, so importing ``repro`` changes no JAX setting.
+Entry points (``chip_smoke.py``, each benchmark's ``__main__``, the
+examples) call :func:`setup_compile_cache` once before their first
+compile; the library never calls it, so importing ``repro`` changes no
+JAX setting.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this helper
   sets nothing.

@@ -34,7 +34,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.flowstate import FlowState
 from repro.net.topology import LinkKind
@@ -120,29 +119,6 @@ class LinkProgram(NamedTuple):
     R: jnp.ndarray          # [F, L] binary routing matrix
     capacity: jnp.ndarray   # [L]
     kind: jnp.ndarray       # [L] LinkKind values
-
-
-def random_problem(n_links: int, n_flows: int = 1_000, seed: int = 0,
-                   links_per_flow: int = 4) -> tuple[LinkProgram, FlowState]:
-    """Seeded controller instance at fabric scale: each flow crosses
-    ``links_per_flow`` random links, link kinds split 40/40/20
-    uplink/downlink/internal like a fat-tree, capacities U(1, 50) MB/s and
-    every FlowState field U(0, 10)."""
-    rng = np.random.default_rng(seed)
-    R = np.zeros((n_flows, n_links), np.float32)
-    for f in range(n_flows):
-        R[f, rng.choice(n_links, size=min(links_per_flow, n_links),
-                        replace=False)] = 1.0
-    kind = rng.choice([0, 1, 2], size=n_links,
-                      p=[0.4, 0.4, 0.2]).astype(np.int32)
-    prog = LinkProgram(
-        R=jnp.asarray(R),
-        capacity=jnp.asarray(rng.uniform(1.0, 50.0, n_links), jnp.float32),
-        kind=jnp.asarray(kind),
-    )
-    st = FlowState(*[jnp.asarray(rng.uniform(0, 10, n_flows), jnp.float32)
-                     for _ in range(5)])
-    return prog, st
 
 
 def _per_link_rates_vmap(program: LinkProgram, state: FlowState, dt: float):

@@ -49,11 +49,11 @@ links costs ~7× its true solve). The runner splits the difference:
      their pad values: they are *inert scenarios* (zero generation/demand,
      huge-capacity INTERNAL links, never-active events) whose rows are
      dropped on return.
-  5. **Device-resident packs** — each staged bucket is pushed to the
-     device(s) once (pre-placed under the scenario-axis sharding) and the
-     same arrays are re-passed on every warm call, so the steady state
-     transfers nothing and converts nothing per call (~10² numpy→device
-     conversions otherwise, milliseconds against a tens-of-ms run).
+  5. **Device-resident packs** — each staged bucket is pushed to its
+     device once and the same arrays are re-passed on every warm call, so
+     the steady state transfers nothing and converts nothing per call
+     (~10² numpy→device conversions otherwise, milliseconds against a
+     tens-of-ms run).
      Earlier revisions donated the input buffers instead; donation and
      input reuse are mutually exclusive, and on the fleet's small packs
      the saved H2D/conversion work beats the saved output allocation.
@@ -130,12 +130,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import (
-    Mesh,
-    NamedSharding,
-    PartitionSpec,
-    SingleDeviceSharding,
-)
+from jax.sharding import SingleDeviceSharding
 
 from repro.core.tcp import maxmin_fused
 from repro.net.topology import LinkKind
@@ -471,17 +466,33 @@ def _plan_buckets(sims: Sequence[CompiledSim], max_buckets: int,
     return buckets
 
 
-def _round_rows(n: int, n_dev: int) -> int:
-    """Padded batch-row capacity for a bucket of ``n`` scenarios: rounded
-    up to the device count (so the scenario axis always shards evenly) and,
-    for fleets large enough that a few inert rows are noise (≥ 16), to a
-    small quantum — growth headroom, so a fleet that only gains scenarios
-    within the padded capacity reuses its compiled executable."""
-    n = -(-n // max(n_dev, 1)) * max(n_dev, 1)
+def _round_rows(n: int) -> int:
+    """Padded batch-row capacity for a bucket (or campaign chunk) of ``n``
+    scenarios: for fleets large enough that a few inert rows are noise
+    (≥ 16), rounded up to a quantum of 4 — growth headroom, so a fleet that
+    only gains scenarios within the padded capacity reuses its compiled
+    executable. Independent of the device count: a bucket or chunk always
+    runs whole on one device (see :meth:`FleetRunner.run`)."""
     if n >= 16:
-        q = 4 * max(n_dev, 1) // math.gcd(4, max(n_dev, 1))
-        n = -(-n // q) * q
+        n = -(-n // 4) * 4
     return n
+
+
+def _assign_devices(plan, rows: list[int], n_dev: int, policy: str,
+                    tick_overhead: float) -> list[int]:
+    """Device index per bucket for a sharded ``run``: longest-processing-
+    time-first over the planner's own cost model (per-bucket tick overhead
+    plus padded FLOPs per row), ties to the lowest device index, so the
+    assignment is deterministic."""
+    cost = [tick_overhead + r * _flop_cost(shape, policy)
+            for (_, shape), r in zip(plan, rows)]
+    load = [0.0] * n_dev
+    owner = [0] * len(plan)
+    for b in sorted(range(len(plan)), key=lambda b: -cost[b]):
+        d = min(range(n_dev), key=lambda d: load[d])
+        owner[b] = d
+        load[d] += cost[b]
+    return owner
 
 
 # chunk_rows="auto" bounds: the floor keeps chunks at the staging quantum
@@ -830,13 +841,14 @@ class FleetRunner:
     no-recompile assertions:
 
     * the jitted executable per (pack signature, policy, solver, n_ticks,
-      upd_every, dt, device count) key (``compile_cache_size`` exposes the
+      upd_every, dt) key (``compile_cache_size`` exposes the
       XLA cache-miss count across them),
     * the numpy staging buffers per (bucket shape, members, rows),
     * the bucket plan per (fleet shape multiset, policy).
 
     ``fused=True`` (default) runs every bucket of a plan inside one jitted
-    executable: a warm fleet run is exactly ONE kernel dispatch.
+    executable: a warm fleet run is exactly ONE kernel dispatch per device
+    it uses (one, unless ``run(shard=True)`` spreads the buckets).
     ``fused=False`` dispatches each bucket as its own executable — the
     per-bucket parity oracle (and the mode the ``fleet_dispatch_floor``
     bench uses to measure per-dispatch overhead). ``simulate_many`` routes
@@ -875,7 +887,6 @@ class FleetRunner:
         self._filled: dict[tuple, list] = {}  # staging key -> sim weakrefs
         self._plan_cache: dict[tuple, list[tuple[list[int], FleetShape]]] = {}
         self._executables: dict[tuple, "jax.stages.Wrapped"] = {}
-        self._shardings: dict[int, tuple] = {}
         # campaign ping/pong staging slots: (shape, rows, phase) -> buffers
         self._campaign_bufs: dict[tuple, dict[str, np.ndarray]] = {}
         self.last_stats: dict = {}
@@ -896,19 +907,6 @@ class FleetRunner:
                                  tick_overhead=self.tick_overhead)
             self._plan_cache[key] = plan
         return plan
-
-    def _sharding(self, n_shards: int):
-        """(batch, replicated) shardings for the scenario axis, or (None,
-        None) single-device."""
-        if n_shards <= 1:
-            return None, None
-        cached = self._shardings.get(n_shards)
-        if cached is None:
-            mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("scenarios",))
-            cached = (NamedSharding(mesh, PartitionSpec("scenarios")),
-                      NamedSharding(mesh, PartitionSpec()))
-            self._shardings[n_shards] = cached
-        return cached
 
     # ----------------------------------------------------------- staging
     def _fill_bucket(self, bufs: dict[str, np.ndarray],
@@ -1000,7 +998,7 @@ class FleetRunner:
                 self._stacked.pop(k, None)
                 self._filled.pop(k, None)
         # restaging mutates the numpy buffers in place: every device copy
-        # of this key (any n_shards variant) and of evicted keys is stale
+        # of this key (on any device) and of evicted keys is stale
         for dk in [d for d in self._device if d[0] == key or d[0] in evict]:
             self._device.pop(dk, None)
         bufs = self._staging.setdefault(key, {})
@@ -1015,7 +1013,7 @@ class FleetRunner:
         return stacked, key, True
 
     # --------------------------------------------------------- executable
-    def _executable(self, key, n_shards: int, policy: str,
+    def _executable(self, key, policy: str,
                     n_ticks: int, dt: float, upd_every: int, alpha: float,
                     n_groups: int, solver: str, t_event: float = 0.0):
         """Build (and cache) the jitted entry point for one pack of
@@ -1031,14 +1029,10 @@ class FleetRunner:
         per-bucket scans inside one program are bitwise-identical to
         separate executables.
 
-        With ``n_shards`` > 1 every bucket's scenario axis is split across
-        local devices as plain SPMD sharding (``jit`` + ``in_shardings``;
-        the fused fixed-trip max-min solver left no data-dependent control
-        flow, see PR 4 — ``shard_map`` is unnecessary). The stacked packs
-        arrive pre-placed under the same shardings and are *not* donated:
-        the runner re-passes the identical device buffers on every warm
-        call, so the steady state pays zero H2D transfer — donation would
-        consume them (see module docstring).
+        The program runs on the device its packs were placed on. The packs
+        are *not* donated: the runner re-passes the identical device
+        buffers on every warm call, so the steady state pays zero H2D
+        transfer — donation would consume them (see module docstring).
         """
         fn = self._executables.get(key)
         if fn is not None:
@@ -1062,11 +1056,7 @@ class FleetRunner:
                         stacked, xf, enf, qcap))
             return tuple(outs)
 
-        batch, rep = self._sharding(n_shards)
-        if batch is not None:
-            fn = jax.jit(impl, in_shardings=(batch, batch, batch, rep))
-        else:
-            fn = jax.jit(impl)
+        fn = jax.jit(impl)
         self._executables[key] = fn
         return fn
 
@@ -1093,9 +1083,14 @@ class FleetRunner:
         policy (appfair buckets by exact app count).
 
         With >1 local device (e.g. ``--xla_force_host_platform_device_count``
-        on CPU, or a TPU slice) and ``shard=True``, each bucket's scenario
-        axis is sharded across devices (bucket rows are padded with inert
-        scenarios up to a device multiple and dropped on return).
+        on CPU, or a TPU host) and ``shard=True``, the buckets are spread
+        over the devices, each bucket whole on one device, and every
+        device runs its buckets as one fused executable (one dispatch per
+        device used). A bucket is never split: on a TPU a program's
+        results change in the last bits with its batch row count (measured
+        on v5e), so only whole buckets keep the sharded run bitwise-equal
+        to the unsharded one — the rule the streaming campaign follows for
+        its chunks.
         """
         if not sims:
             raise ValueError("empty fleet")
@@ -1104,27 +1099,26 @@ class FleetRunner:
             raise ValueError("x_fixed must give one rate vector per scenario")
         n_ticks = int(round(smoke_seconds(seconds) / dt))
         upd_every = resolve_upd_every(policy, dt, upd_every)
-        n_dev = len(jax.devices()) if shard else 1
+        devices = jax.devices()
+        n_dev = len(devices) if shard else 1
 
         plan = self.plan(sims, policy)
-        row_counts = [_round_rows(len(idxs), n_dev) for idxs, _ in plan]
-        n_shards = n_dev if (n_dev > 1
-                             and all(r % n_dev == 0 for r in row_counts)
-                             ) else 1
-        batch_sh, _ = self._sharding(n_shards)
+        row_counts = [_round_rows(len(idxs)) for idxs, _ in plan]
+        owner = (_assign_devices(plan, row_counts, n_dev, policy,
+                                 self.tick_overhead)
+                 if n_dev > 1 else [0] * len(plan))
         packs, xfs, enfs = [], [], []
-        for (idxs, shape), rows in zip(plan, row_counts):
+        for (idxs, shape), rows, d in zip(plan, row_counts, owner):
             stacked, skey, fresh = self._stack_bucket(
                 [sims[i] for i in idxs], shape, idxs, rows)
-            # device-resident pack: pushed (pre-sharded) once per staging,
-            # re-passed verbatim on warm calls — zero per-call transfer
-            # (restaging purges every device variant of the key)
-            dkey = (skey, n_shards)
+            # device-resident pack: pushed once per staging onto the
+            # bucket's device, re-passed verbatim on warm calls — zero
+            # per-call transfer (restaging purges every device copy)
+            dkey = (skey, d if n_dev > 1 else None)
             dev = self._device.get(dkey)
             if dev is None:
-                dev = (jax.device_put(stacked, batch_sh)
-                       if batch_sh is not None else
-                       jax.tree_util.tree_map(jnp.asarray, stacked))
+                dev = (jax.device_put(stacked, devices[d]) if n_dev > 1
+                       else jax.tree_util.tree_map(jnp.asarray, stacked))
                 self._device[dkey] = dev
             packs.append(dev)
             if x_fixed is None:
@@ -1146,38 +1140,38 @@ class FleetRunner:
             for b, i in enumerate(idxs):
                 enf[b] = sims[i].is_dynamic
             enfs.append(enf)
-        pack_sig = tuple((dataclasses.astuple(shape), rows)
-                         for (_, shape), rows in zip(plan, row_counts))
+        pack_sig = [(dataclasses.astuple(shape), rows)
+                    for (_, shape), rows in zip(plan, row_counts)]
         base_key = (policy, n_ticks, dt, upd_every, alpha, n_groups, solver,
-                    n_shards, x_fixed is not None, float(t_event))
-
+                    x_fixed is not None, float(t_event))
         if self.fused:
-            fn = self._executable(
-                base_key + (pack_sig,), n_shards, policy,
-                n_ticks, dt, upd_every, alpha, n_groups, solver,
-                t_event=float(t_event))
-            outs = fn(tuple(packs), tuple(xfs), tuple(enfs),
-                      jnp.float32(qcap))
-            n_dispatches = 1
+            # one fused executable per device, over that device's buckets
+            groups: dict[int, list[int]] = {}
+            for b, d in enumerate(owner):
+                groups.setdefault(d, []).append(b)
         else:
             # per-bucket oracle: one executable (and one dispatch) per
             # bucket; jax dispatch is async, so bucket k+1's staging
             # overlaps bucket k's compute
-            outs = []
-            for pack, xf, enf, sig in zip(packs, xfs, enfs, pack_sig):
-                fn = self._executable(
-                    base_key + (sig,), n_shards, policy, n_ticks,
-                    dt, upd_every, alpha, n_groups, solver,
-                    t_event=float(t_event))
-                outs.append(fn((pack,), (xf,), (enf,),
-                               jnp.float32(qcap))[0])
-            n_dispatches = len(plan)
+            groups = {b: [b] for b in range(len(plan))}
+        outs: list = [None] * len(plan)
+        for bs in groups.values():
+            fn = self._executable(
+                base_key + (tuple(pack_sig[b] for b in bs),), policy,
+                n_ticks, dt, upd_every, alpha, n_groups, solver,
+                t_event=float(t_event))
+            res = fn(tuple(packs[b] for b in bs), tuple(xfs[b] for b in bs),
+                     tuple(enfs[b] for b in bs), jnp.float32(qcap))
+            for b, r in zip(bs, res):
+                outs[b] = r
 
         self.last_stats = {
-            "n_dispatches": n_dispatches,
+            "n_dispatches": len(groups),
             "n_buckets": len(plan),
             "n_scenarios": len(sims),
-            "n_shards": n_shards,
+            "n_shards": len(set(owner)),
+            "bucket_devices": [str(next(iter(o[-1].devices())))
+                               for o in outs],
             "rows": row_counts,
             "bucket_shapes": [dataclasses.astuple(s) for _, s in plan],
             "policy": policy,
@@ -1326,7 +1320,7 @@ class FleetRunner:
             # share identical padded shapes (hence identical programs and
             # bitwise-identical metrics) — the shard changes where a chunk
             # runs, never what it computes
-            cap_rows.append(_round_rows(per, 1))
+            cap_rows.append(_round_rows(per))
             jobs.extend((bi, idxs[lo:lo + per])
                         for lo in range(0, len(idxs), per))
         # scenario-axis shard of the chunk stream: chunk j runs on device
@@ -1338,10 +1332,10 @@ class FleetRunner:
         stream_sh = [SingleDeviceSharding(d)
                      for d in jax.devices()[:n_streams]]
         base_key = (policy, n_ticks, dt, upd_every, alpha, n_groups, solver,
-                    1, x_fixed is not None, float(t_event))
+                    x_fixed is not None, float(t_event))
         fns = [self._executable(
                    base_key + (((dataclasses.astuple(shape), rows),),),
-                   1, policy, n_ticks, dt, upd_every, alpha,
+                   policy, n_ticks, dt, upd_every, alpha,
                    n_groups, solver, t_event=float(t_event))
                for (_, shape), rows in zip(plan, cap_rows)]
 

@@ -418,16 +418,10 @@ def _metrics_epilogue(sink, wait, load, caps_grid, path_w, dt: float,
     zero ``path_w`` weight, padded links carry zero load against huge
     capacity, so padding never moves a metric.
 
-    Known ULP-level sensitivity: ``sink.sum()`` (the ``total_sink_mb``
-    entry) is the epilogue's only full-length un-normalized reduction, and
-    XLA re-associates its reduction tree when the batch axis is
-    SPMD-sharded (a 4-device ``run`` lowers a different tree for the
-    per-device row count than the unsharded bucket). Trajectories and
-    every other metric are bitwise under sharding; a regression test pins
-    the drift to this one op at a couple of ULP
-    (``tests/test_multidevice.py``). Not "fixed" by a
-    sequential accumulator on purpose — that would change the unsharded
-    value and break bitwise continuity of existing static-fleet results.
+    The batch row count of the compiled program can move the last bits of
+    these reductions (on CPU ``sink.sum()``, on a TPU v5e the trajectories
+    as well), which is why the fleet engine never splits a bucket across
+    devices: equal row counts give bitwise-equal rows.
     """
     T = sink.shape[0]
     warm = T // 4

@@ -20,7 +20,6 @@ from repro.core.allocator import (
     _per_link_rates,
     _per_link_rates_vmap,
     allocate,
-    random_problem,
 )
 from repro.net import big_switch, fat_tree, LinkKind
 
@@ -344,6 +343,8 @@ class TestAlgorithm1:
     def test_random_problem_seeded_and_feasible(self):
         # the fabric-scale controller instance, cut to a CPU size: seeded,
         # 4 links per flow, and both solvers agree and stay feasible
+        from benchmarks.allocator import random_problem
+
         prog, st_ = random_problem(300, 64, seed=7)
         again, st2 = random_problem(300, 64, seed=7)
         np.testing.assert_array_equal(prog.R, again.R)

@@ -14,7 +14,8 @@ policy to .npy files and the parent (1 stream, ``shard=False``) compares
 bitwise. In-child invariants: campaign == unsharded materialized oracle,
 repeat call bitwise-stable with a flat compile cache, host staging
 bounded by the three rotating slots per stream, and all four devices
-actually used.
+actually used. The sharded materialized ``run`` (whole buckets spread over
+the devices) is bitwise-equal to the unsharded one.
 """
 import json
 import os
@@ -68,15 +69,14 @@ for policy in %(policies)r:
                     "transfer_s": st["transfer_s"],
                     "peak_staged_rows": st["peak_staged_rows"],
                     "chunk_rows": st["chunk_rows"]}
-# the known SPMD sensitivity of the batch-sharded `run` path: sharding a
-# bucket's scenario axis re-associates exactly one epilogue reduction —
-# total_sink_mb, the only full-length un-normalized sum — by at most 1 ULP;
-# trajectories and every other metric stay bitwise (see the
-# `_metrics_epilogue` docstring). Recorded here, asserted by the parent.
+# the sharded `run` places whole buckets on devices, so each bucket runs
+# the program the unsharded run runs for it: trajectories and metrics are
+# compared bitwise here, asserted by the parent
 from repro.streams.simulator import metric_index
 ulp = {}
 for policy in ("tcp", "appaware"):
     sh = runner.run(sims, policy, seconds=seconds, dt=dt, shard=True)
+    st_sh = dict(runner.last_stats)
     un = runner.run(sims, policy, seconds=seconds, dt=dt, shard=False)
     traj_equal = all(
         np.array_equal(a.sink_mb, b.sink_mb)
@@ -90,7 +90,11 @@ for policy in ("tcp", "appaware"):
                          - mu.view(np.int32).astype(np.int64)).max())
     ulp[policy] = {"traj_equal": bool(traj_equal),
                    "diff_cols": [int(c) for c in diff_cols],
-                   "max_ulp": max_ulp}
+                   "max_ulp": max_ulp,
+                   "n_buckets": st_sh["n_buckets"],
+                   "n_shards": st_sh["n_shards"],
+                   "n_dispatches": st_sh["n_dispatches"],
+                   "bucket_devices": st_sh["bucket_devices"]}
 info["ulp_pin"] = {"sink_col": metric_index("total_sink_mb"),
                    "policies": ulp}
 with open(f"{out_dir}/stats.json", "w") as f:
@@ -161,18 +165,19 @@ class TestShardedCampaignParity:
 
     def test_sharded_run_drift_confined_to_total_sink_mb(
             self, four_device_run):
-        """Pin the one tolerated SPMD sensitivity of the materialized
-        ``run`` path: with the bucket's scenario axis sharded over 4
-        devices, trajectories are bitwise-equal to the unsharded run and
-        the epilogue metrics differ — if at all — only in the
-        ``total_sink_mb`` column, by a couple of ULP (observed ≤ 2 on the
-        54-scenario corpus). Anything wider (a new drifting op, a larger
-        drift, a drifting trajectory) is a regression, not more of the
-        same."""
+        """The sharded materialized ``run`` against the unsharded one: the
+        buckets spread over the devices (one fused dispatch per device
+        used), each bucket whole on one device, so trajectories AND every
+        metric column — ``total_sink_mb`` included, which drifted by a
+        couple of ULP while buckets were split across devices — are
+        bitwise-equal."""
         _, stats = four_device_run
         pin = stats["ulp_pin"]
-        sink_col = pin["sink_col"]
         for policy, rec in pin["policies"].items():
             assert rec["traj_equal"], policy
-            assert set(rec["diff_cols"]) <= {sink_col}, (policy, rec)
-            assert rec["max_ulp"] <= 4, (policy, rec)
+            assert rec["diff_cols"] == [] and rec["max_ulp"] == 0, (
+                policy, rec)
+            n_used = min(4, rec["n_buckets"])
+            assert rec["n_shards"] == n_used > 1, (policy, rec)
+            assert rec["n_dispatches"] == n_used, (policy, rec)
+            assert len(set(rec["bucket_devices"])) == n_used, (policy, rec)

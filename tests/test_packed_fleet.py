@@ -64,7 +64,9 @@ class TestPackedVsPerBucketParity:
         per_bucket = FleetRunner(fused=False)
         a = packed.run(corpus, policy, seconds=SECONDS, dt=DT, **kw)
         b = per_bucket.run(corpus, policy, seconds=SECONDS, dt=DT, **kw)
-        assert packed.last_stats["n_dispatches"] == 1
+        # one fused dispatch per device used (one on a single device)
+        assert (packed.last_stats["n_dispatches"]
+                == packed.last_stats["n_shards"])
         assert (per_bucket.last_stats["n_dispatches"]
                 == per_bucket.last_stats["n_buckets"])
         for ra, rb in zip(a, b):
@@ -99,7 +101,8 @@ class TestSingleDispatch:
 
         sims = [two_app(2, 1.25), two_app(3, 1.875), two_app(2, 2.5)]
         runner = FleetRunner(fused=True)
-        batch = runner.run(sims, "appfair", seconds=SECONDS, dt=DT)
+        batch = runner.run(sims, "appfair", seconds=SECONDS, dt=DT,
+                           shard=False)
         assert runner.last_stats["n_dispatches"] == 1
         assert runner.last_stats["n_buckets"] == 2  # one per app count
         for sim, rb in zip(sims, batch):
@@ -114,6 +117,37 @@ class TestSingleDispatch:
         fixed_plan = runner.plan(corpus, "fixed")
         tcp_plan = runner.plan(corpus, "tcp")
         assert len(fixed_plan) < len(tcp_plan) <= runner.max_buckets
+
+
+class TestDevicePlacement:
+    """``run(shard=True)`` spreads whole buckets over the devices; the
+    assignment is pure host logic, checked here for any device count."""
+
+    @pytest.mark.parametrize("n_dev", [1, 2, 3, 4, 8])
+    def test_whole_buckets_spread_deterministically(self, corpus, n_dev):
+        from repro.streams.fleet import (
+            _assign_devices,
+            _flop_cost,
+            _round_rows,
+        )
+        runner = FleetRunner()
+        plan = runner.plan(corpus, "tcp")
+        rows = [_round_rows(len(idxs)) for idxs, _ in plan]
+        owner = _assign_devices(plan, rows, n_dev, "tcp",
+                                runner.tick_overhead)
+        assert owner == _assign_devices(plan, rows, n_dev, "tcp",
+                                        runner.tick_overhead)
+        assert len(owner) == len(plan)
+        assert all(0 <= d < n_dev for d in owner)
+        assert len(set(owner)) == min(n_dev, len(plan))
+        # longest first: the costliest bucket opens device 0
+        cost = [r * _flop_cost(s, "tcp") for (_, s), r in zip(plan, rows)]
+        assert owner[int(np.argmax(cost))] == 0
+
+    def test_rows_do_not_depend_on_device_count(self):
+        from repro.streams.fleet import _round_rows
+        assert [_round_rows(n) for n in (1, 2, 3, 15, 16, 17, 21)] == [
+            1, 2, 3, 15, 16, 20, 24]
 
 
 class TestEnforcementMask:

@@ -69,7 +69,7 @@ def campaign_texts(one_chip):
                 **{k: _spec(v.shape, v.dtype, one_chip)
                    for k, v in leaves.items()})
             fn = runner._executable(
-                ("tpu", policy), 1, policy, N_TICKS, 0.5,
+                ("tpu", policy), policy, N_TICKS, 0.5,
                 resolve_upd_every(policy, 0.5, None), 0.5, 8, "sort")
             enf = _spec((CHUNK_ROWS,), np.bool_, one_chip)
             qcap = _spec((), jnp.float32, one_chip)
