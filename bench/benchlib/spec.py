@@ -1,0 +1,78 @@
+"""Resolve a benchmark cell by name to its files.
+
+Everything that belongs to one configuration, one traffic mix or one
+per-layer metric sits in a file of its own, found by the name that
+``BENCHMARK.json`` gives it:
+
+* configuration ``<c>``: the ``file`` of its entry (``bench/configs/<c>.json``);
+* traffic mix ``<t>``: ``bench/traffic/<t>.json``;
+* per-layer metric ``<m>``: ``bench/metrics/<m>.py``, a module with
+  ``read(ctx) -> float | None``.
+
+Adding any of them is adding a file; no file here changes.
+"""
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH_DIR)
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict
+    traffic: dict
+    end_to_end: list[dict]     # metric entries the cell reports, in order
+    per_layer: list[dict]
+
+
+def load_benchmark(root: str = ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _applies(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def traffic_path(name: str, root: str = ROOT) -> str:
+    return os.path.join(root, "bench", "traffic", f"{name}.json")
+
+
+def metric_path(name: str, root: str = ROOT) -> str:
+    return os.path.join(root, "bench", "metrics", f"{name}.py")
+
+
+def resolve(workload: str, root: str = ROOT, bench: dict | None = None
+            ) -> Cell:
+    bench = load_benchmark(root) if bench is None else bench
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        raise KeyError(f"no workload {workload!r} in BENCHMARK.json "
+                       f"(have {sorted(cells)})")
+    w = cells[workload]
+    configs = {c["name"]: c for c in bench["configs"]}
+    with open(os.path.join(root, configs[w["config"]]["file"])) as f:
+        config = json.load(f)
+    with open(traffic_path(w["traffic"], root)) as f:
+        traffic = json.load(f)
+    return Cell(
+        name=workload, chips=int(w["chips"]), config=config, traffic=traffic,
+        end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
+        per_layer=[m for m in bench["per_layer"] if _applies(m, workload)])
+
+
+def load_reader(name: str, root: str = ROOT):
+    """The ``read`` function of per-layer metric ``name``."""
+    path = metric_path(name, root)
+    spec = importlib.util.spec_from_file_location(
+        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
