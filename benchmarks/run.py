@@ -1,5 +1,5 @@
-"""Benchmark runner — one section per paper table/figure plus the roofline
-table from the dry-run. Prints ``name,us_per_call,derived`` CSV."""
+"""Benchmark runner — one section per paper table/figure. Prints
+``name,us_per_call,derived`` CSV."""
 from __future__ import annotations
 
 import sys
@@ -14,7 +14,6 @@ def main() -> None:
         latency,
         motivation,
         overhead,
-        roofline,
         throughput,
         utilization,
     )
@@ -26,7 +25,6 @@ def main() -> None:
         ("fig12", utilization.main),
         ("fig13", fairness.main),
         ("overhead", overhead.main),
-        ("roofline", roofline.main),
     ]
     only = sys.argv[1] if len(sys.argv) > 1 else None
     failures = 0

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro.core.flowstate import FlowState
 from repro.net.topology import LinkKind
+from repro.spans import span
 
 _EPS = 1e-9
 _INF = jnp.inf
@@ -292,7 +293,8 @@ def backfill(x: jnp.ndarray, program: LinkProgram, iters: int = 8,
         inc = jnp.where(on_net & jnp.isfinite(r_min), x * r_min, 0.0)
         return x + damping * inc
 
-    return jax.lax.fori_loop(0, iters, body, x)
+    with jax.named_scope("backfill"):
+        return jax.lax.fori_loop(0, iters, body, x)
 
 
 @functools.partial(jax.jit, static_argnames=("dt", "backfill_iters", "solver",
@@ -321,18 +323,19 @@ def allocate(
             ``ALLOC_BLOCK_LINKS`` above it. Pass ``0`` to force the
             single-pass form at any size.
     """
-    if solver == "sort":
-        if block_links is None and program.R.shape[1] > 2 * ALLOC_BLOCK_LINKS:
-            block_links = ALLOC_BLOCK_LINKS
-        if block_links:
-            per_link = _per_link_rates_chunked(program, state, dt,
-                                               block_links)   # [L, F]
+    with jax.named_scope("per_link"):
+        if solver == "sort":
+            if block_links is None and program.R.shape[1] > 2 * ALLOC_BLOCK_LINKS:
+                block_links = ALLOC_BLOCK_LINKS
+            if block_links:
+                per_link = _per_link_rates_chunked(program, state, dt,
+                                                   block_links)   # [L, F]
+            else:
+                per_link = _per_link_rates(program, state, dt)     # [L, F]
+        elif solver == "pallas":
+            per_link = _per_link_rates_pallas(program, state, dt)  # [L, F]
         else:
-            per_link = _per_link_rates(program, state, dt)     # [L, F]
-    elif solver == "pallas":
-        per_link = _per_link_rates_pallas(program, state, dt)  # [L, F]
-    else:
-        raise ValueError(f"unknown solver {solver!r}")
+            raise ValueError(f"unknown solver {solver!r}")
     kind = program.kind
 
     # Alg. 1 line 22 collapsed: min(x^u, x^d) over a flow's links is the min
@@ -376,8 +379,11 @@ class OnlineAllocator:
         self.solver = solver
 
     def __call__(self, state: FlowState) -> jnp.ndarray:
-        return allocate(self.program, state, dt=self.dt,
-                        backfill_iters=self.backfill_iters, solver=self.solver)
+        # the span covers argument transfer and the enqueue, not the solve
+        with span("allocator.launch"):
+            return allocate(self.program, state, dt=self.dt,
+                            backfill_iters=self.backfill_iters,
+                            solver=self.solver)
 
     @classmethod
     def from_topology(cls, topo, flows, **kw) -> "OnlineAllocator":

@@ -545,40 +545,41 @@ def maxmin_fused_step(R: jnp.ndarray, capacity: jnp.ndarray,
     Not jitted itself: it is scan-body machinery, traced inside its
     caller (``repro.streams.simulator._run``).
     """
-    F, L = R.shape
-    if rounds is None:
-        rounds = min(F, L) + 1
-    form = _resolve_form(F, form)
-    block_flows = _resolve_block_flows(F, form, block_flows)
-    R = R.astype(jnp.float32)
-    on_net = jnp.sum(R, axis=1) > 0
-    d = jnp.where(on_net, jnp.maximum(demand, 0.0), 0.0)
+    with jax.named_scope("maxmin"):
+        F, L = R.shape
+        if rounds is None:
+            rounds = min(F, L) + 1
+        form = _resolve_form(F, form)
+        block_flows = _resolve_block_flows(F, form, block_flows)
+        R = R.astype(jnp.float32)
+        on_net = jnp.sum(R, axis=1) > 0
+        d = jnp.where(on_net, jnp.maximum(demand, 0.0), 0.0)
 
-    valid0, perm0, A1_0 = carry
-    dp = d[perm0]
-    if F > 1:
-        mono = jnp.all((dp[:-1] < dp[1:])
-                       | ((dp[:-1] == dp[1:]) & (perm0[:-1] < perm0[1:])))
-    else:
-        mono = jnp.array(True)
-    ok = valid0 & mono
-
-    def rebuild(_):
-        if form == "gemm":
-            A1, perm = _order_operand(d)
+        valid0, perm0, A1_0 = carry
+        dp = d[perm0]
+        if F > 1:
+            mono = jnp.all((dp[:-1] < dp[1:])
+                           | ((dp[:-1] == dp[1:]) & (perm0[:-1] < perm0[1:])))
         else:
-            _, perm = _order_matrix(d)
-            A1 = jnp.zeros((0, F), jnp.float32)
-        return perm, A1
+            mono = jnp.array(True)
+        ok = valid0 & mono
 
-    def keep(_):
-        return perm0, A1_0
+        def rebuild(_):
+            if form == "gemm":
+                A1, perm = _order_operand(d)
+            else:
+                _, perm = _order_matrix(d)
+                A1 = jnp.zeros((0, F), jnp.float32)
+            return perm, A1
 
-    perm, A1 = jax.lax.cond(ok, keep, rebuild, None)
-    levels = _levels_fn(form, d, A1, perm, block_flows)
-    x = _fill(R, on_net, d, levels, capacity, rounds)
-    x = jnp.where(on_net, x, demand)
-    return x, (jnp.ones((), bool), perm, A1), ~ok
+        def keep(_):
+            return perm0, A1_0
+
+        perm, A1 = jax.lax.cond(ok, keep, rebuild, None)
+        levels = _levels_fn(form, d, A1, perm, block_flows)
+        x = _fill(R, on_net, d, levels, capacity, rounds)
+        x = jnp.where(on_net, x, demand)
+        return x, (jnp.ones((), bool), perm, A1), ~ok
 
 
 def demand_limited_maxmin_np(R, capacity, demand):

@@ -134,6 +134,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.tcp import maxmin_fused
 from repro.net.topology import LinkKind
+from repro.spans import span
 from repro.streams.faults import (
     FailureRecord,
     FaultPlan,
@@ -1173,7 +1174,6 @@ class FleetRunner:
             "bucket_devices": [str(next(iter(o[-1].devices())))
                                for o in outs],
             "rows": row_counts,
-            "bucket_shapes": [dataclasses.astuple(s) for _, s in plan],
             "policy": policy,
         }
 
@@ -1245,7 +1245,11 @@ class FleetRunner:
         (trajectory transfer re-enabled — only for small campaigns).
         ``last_stats`` gains ``peak_staged_rows`` / ``peak_staged_bytes``,
         the pipeline wall-time split (``stage_s`` / ``transfer_s`` /
-        ``transfer_wait_s`` / ``dispatch_s`` / ``block_s``),
+        ``transfer_wait_s`` / ``dispatch_s`` / ``block_s``, each the
+        seconds of one named span, :mod:`repro.spans`), ``startup_s``
+        (entry to the return of the first dispatch: the stretch in which
+        the device has nothing to run), ``rows_dispatched`` (padded rows
+        of every dispatch, recovery re-runs included),
         ``overlap_fraction`` (share of *hideable* staging hidden behind
         in-flight compute; 1.0 when nothing was hideable — a single-chunk
         campaign has no compute to hide behind) and ``transfer_overlap``
@@ -1296,6 +1300,9 @@ class FleetRunner:
         n_dev = len(jax.devices()) if shard else 1
 
         t_wall0 = time.perf_counter()
+        tot = dict.fromkeys(("startup_s", "stage_s", "transfer_s",
+                             "transfer_wait_s", "dispatch_s", "block_s"), 0.0)
+        startup = span("campaign.startup", tot, "startup_s").__enter__()
         calib = calibrate_backend()
         plan = self.plan(sims, policy)
         # fixed padded row count per bucket, chunks BALANCED within it:
@@ -1343,8 +1350,6 @@ class FleetRunner:
         metrics_all = np.empty((len(sims), n_metrics), np.float32)
         results: list[SimResult | None] | None = (
             [None] * len(sims) if retain_trajectories else None)
-        stage_s = dispatch_s = block_s = 0.0
-        transfer_s = transfer_wait_s = 0.0
         hidden_stage_s = hideable_stage_s = 0.0
         peak_rows = peak_bytes = 0
         inflight_total = 0
@@ -1358,7 +1363,7 @@ class FleetRunner:
 
         # ---- resilience state (inert on the fault-free path) ----
         failures: list[FailureRecord] = []
-        n_retries = n_recovered = n_dispatched = 0
+        n_retries = n_recovered = n_dispatched = rows_dispatched = 0
         chunks_done = 0
         chunks_on: dict[str, int] = {}  # device -> pipeline chunks run there
         rec_col = metric_index("recovery_time_s")
@@ -1399,53 +1404,60 @@ class FleetRunner:
                 _checkpoint_append(ckpt_dir, ckpt_fp, j, idxs,
                                    metrics_all[list(idxs)].copy(), fl)
 
+        def _dispatched(rows):
+            nonlocal n_dispatched, rows_dispatched
+            n_dispatched += 1
+            rows_dispatched += rows
+            startup.close()
+
         def _h2d(host_pack, sh, j):
             # transfer worker. NOTE: on CPU, device_put zero-copy aliases
             # 64-byte-aligned numpy buffers instead of copying (measured),
             # so a resolved future does NOT mean the host slot is free —
             # the triple-buffered slot rotation below owns that invariant
-            t0 = time.perf_counter()
-            _fire("transfer", j)
-            dev = jax.device_put(host_pack, sh)
-            jax.block_until_ready(dev)
-            return dev, time.perf_counter() - t0
+            with span("campaign.h2d") as sp:
+                _fire("transfer", j)
+                dev = jax.device_put(host_pack, sh)
+                jax.block_until_ready(dev)
+            return dev, sp.seconds
 
         def _collect_oldest(s):
-            nonlocal block_s, inflight_total
+            nonlocal inflight_total
             j, bi, idxs, chunk, outs = inflight[s].pop(0)
-            t0 = time.perf_counter()
-            # block ONLY on the [rows, n_metrics] epilogue leaf; the [T, …]
-            # trajectory outputs stay on device and free when `outs` drops
-            try:
-                m = np.asarray(outs[6])
-            except Exception as e:  # noqa: BLE001 — route to recovery
-                inflight_total -= 1
-                block_s += time.perf_counter() - t0
-                _recover_chunk(bi, j, idxs, chunk, e)
-                return
-            if faults is not None and faults.poison:
-                # copy before poisoning: np.asarray of a device array may
-                # be a read-only (or aliasing) view
-                m = np.array(m)
-                m[:len(idxs)][faults.poison_mask(idxs)] = np.nan
-            bad = None
-            if finite_check:
-                ok = _slab_rows_ok(m[:len(idxs)])
-                if not ok.all():
-                    bad = ~ok
-            for b, i in enumerate(idxs):
-                if bad is None or not bad[b]:
-                    metrics_all[i] = m[b]
-            for d in outs[6].devices():
-                chunks_on[str(d)] = chunks_on.get(str(d), 0) + 1
-            if results is not None:
-                host = [np.asarray(o) for o in outs[:6]]
-                for b, i in enumerate(idxs):
-                    if bad is None or not bad[b]:
-                        results[i] = result_from_padded_row(
-                            chunk[b], b, dt, *host, m)
             inflight_total -= 1
-            block_s += time.perf_counter() - t0
+            err = bad = None
+            with span("campaign.collect", tot, "block_s"):
+                # block ONLY on the [rows, n_metrics] epilogue leaf; the
+                # [T, …] trajectory outputs stay on device and free when
+                # `outs` drops
+                try:
+                    m = np.asarray(outs[6])
+                except Exception as e:  # noqa: BLE001 — route to recovery
+                    err = e
+                else:
+                    if faults is not None and faults.poison:
+                        # copy before poisoning: np.asarray of a device
+                        # array may be a read-only (or aliasing) view
+                        m = np.array(m)
+                        m[:len(idxs)][faults.poison_mask(idxs)] = np.nan
+                    if finite_check:
+                        ok = _slab_rows_ok(m[:len(idxs)])
+                        if not ok.all():
+                            bad = ~ok
+                    for b, i in enumerate(idxs):
+                        if bad is None or not bad[b]:
+                            metrics_all[i] = m[b]
+                    for d in outs[6].devices():
+                        chunks_on[str(d)] = chunks_on.get(str(d), 0) + 1
+                    if results is not None:
+                        host = [np.asarray(o) for o in outs[:6]]
+                        for b, i in enumerate(idxs):
+                            if bad is None or not bad[b]:
+                                results[i] = result_from_padded_row(
+                                    chunk[b], b, dt, *host, m)
+            if err is not None:
+                _recover_chunk(bi, j, idxs, chunk, err)
+                return
             if bad is not None:
                 # non-finite rows: good rows above are final (vmap rows
                 # are independent); bisect only the poisoned ones
@@ -1455,17 +1467,15 @@ class FleetRunner:
             _chunk_complete(j, idxs)
 
         def _dispatch(s):
-            nonlocal dispatch_s, transfer_s, transfer_wait_s
-            nonlocal inflight_total, n_dispatched
+            nonlocal inflight_total
             bi, j, idxs, chunk, fut = pending[s]
             pending[s] = None
-            t0 = time.perf_counter()
             try:
-                (pack, xf, enf), t_copy = (
-                    fut.result() if transfer_timeout_s is None
-                    else fut.result(timeout=transfer_timeout_s))
+                with span("campaign.wait_h2d", tot, "transfer_wait_s"):
+                    (pack, xf, enf), t_copy = (
+                        fut.result() if transfer_timeout_s is None
+                        else fut.result(timeout=transfer_timeout_s))
             except FuturesTimeoutError:
-                transfer_wait_s += time.perf_counter() - t0
                 # hung transfer: the worker may be wedged in a driver
                 # call, so abandon the whole executor (the hung thread
                 # leaks until it returns; its eventual device_put result
@@ -1480,21 +1490,18 @@ class FleetRunner:
                 # CancelledError is a BaseException since 3.8 but here
                 # only means "the watchdog replaced the executor while
                 # this stream's copy was queued" — recoverable
-                transfer_wait_s += time.perf_counter() - t0
                 _recover_chunk(bi, j, idxs, chunk, e)
                 return
-            transfer_wait_s += time.perf_counter() - t0
-            transfer_s += t_copy
-            t0 = time.perf_counter()
+            tot["transfer_s"] += t_copy
             try:
-                _fire("dispatch", j)
-                outs = fns[bi]((pack,), (xf,), (enf,), jnp.float32(qcap))[0]
+                with span("campaign.dispatch", tot, "dispatch_s"):
+                    _fire("dispatch", j)
+                    outs = fns[bi]((pack,), (xf,), (enf,),
+                                   jnp.float32(qcap))[0]
             except Exception as e:  # noqa: BLE001 — route to recovery
-                dispatch_s += time.perf_counter() - t0
                 _recover_chunk(bi, j, idxs, chunk, e)
                 return
-            n_dispatched += 1
-            dispatch_s += time.perf_counter() - t0
+            _dispatched(cap_rows[bi])
             inflight[s].append((j, bi, idxs, chunk, outs))
             inflight_total += 1
             if len(inflight[s]) > 1:
@@ -1523,7 +1530,6 @@ class FleetRunner:
             subset. Staging goes into FRESH scratch buffers — never the
             rotating pipeline slots, which an in-flight (or abandoned)
             transfer may still alias."""
-            nonlocal n_dispatched
             shape = plan[bi][1]
             rows = cap_rows[bi]
             _fire("pack", j)
@@ -1544,7 +1550,7 @@ class FleetRunner:
                                              stream_sh[s])
             _fire("dispatch", j)
             outs = fns[bi]((pack,), (xfd,), (enfd,), jnp.float32(qcap))[0]
-            n_dispatched += 1
+            _dispatched(rows)
             m = np.array(np.asarray(outs[6])[:len(idxs)])
             if faults is not None and faults.poison:
                 m[faults.poison_mask(idxs)] = np.nan
@@ -1672,61 +1678,58 @@ class FleetRunner:
                 shape_t = dataclasses.astuple(shape)
                 chunk = [sims[i] for i in idxs]
                 # --- stage chunk j into this stream's rotating slot ---
-                t0 = time.perf_counter()
                 try:
-                    _fire("pack", j)
-                    # THREE slot phases, one per pipeline stage:
-                    # device_put on CPU zero-copy ALIASES any
-                    # 64-byte-aligned numpy buffer (measured; whether a
-                    # given np.empty lands aligned is allocator luck), so
-                    # a slot may only be refilled once its previous
-                    # occupant's *execution* has been collected — not
-                    # merely once its transfer resolved. The pipeline lags
-                    # staging by at most two chunks (one pending transfer
-                    # plus one uncollected dispatch: the forced dispatch
-                    # before every submit collects down to a single
-                    # in-flight chunk), so phase c%3 — last filled for
-                    # chunk c-3, collected during chunk c-2's dispatch —
-                    # is guaranteed idle. Slots of any OTHER shape on this
-                    # stream are dropped (an in-progress transfer keeps
-                    # the numpy alive via its own reference; dropping the
-                    # dict entry never mutates)
-                    for k in [k for k in self._campaign_bufs
-                              if k[2] == s and k[:2] != (shape_t, rows)]:
-                        del self._campaign_bufs[k]
-                    bufs = self._campaign_bufs.setdefault(
-                        (shape_t, rows, s, staged_n[s] % 3), {})
-                    leaves = self._fill_bucket(bufs, chunk, shape, rows)
-                    stacked = CompiledSim(tuples_per_mb=1.0,
-                                          n_apps=shape.n_apps, **leaves)
-                    if x_fixed is None:
-                        xf = None
-                    else:
-                        xf = np.zeros((rows, shape.n_flows), np.float32)
-                        for b, i in enumerate(idxs):
-                            xf[b, :len(x_fixed[i])] = np.asarray(
-                                x_fixed[i], np.float32)
-                    enf = np.zeros(rows, bool)
-                    for b, sim in enumerate(chunk):
-                        enf[b] = sim.is_dynamic
+                    with span("campaign.stage", tot, "stage_s") as staging:
+                        _fire("pack", j)
+                        # THREE slot phases, one per pipeline stage:
+                        # device_put on CPU zero-copy ALIASES any
+                        # 64-byte-aligned numpy buffer (measured; whether a
+                        # given np.empty lands aligned is allocator luck), so
+                        # a slot may only be refilled once its previous
+                        # occupant's *execution* has been collected — not
+                        # merely once its transfer resolved. The pipeline lags
+                        # staging by at most two chunks (one pending transfer
+                        # plus one uncollected dispatch: the forced dispatch
+                        # before every submit collects down to a single
+                        # in-flight chunk), so phase c%3 — last filled for
+                        # chunk c-3, collected during chunk c-2's dispatch —
+                        # is guaranteed idle. Slots of any OTHER shape on this
+                        # stream are dropped (an in-progress transfer keeps
+                        # the numpy alive via its own reference; dropping the
+                        # dict entry never mutates)
+                        for k in [k for k in self._campaign_bufs
+                                  if k[2] == s and k[:2] != (shape_t, rows)]:
+                            del self._campaign_bufs[k]
+                        bufs = self._campaign_bufs.setdefault(
+                            (shape_t, rows, s, staged_n[s] % 3), {})
+                        leaves = self._fill_bucket(bufs, chunk, shape, rows)
+                        stacked = CompiledSim(tuples_per_mb=1.0,
+                                              n_apps=shape.n_apps, **leaves)
+                        if x_fixed is None:
+                            xf = None
+                        else:
+                            xf = np.zeros((rows, shape.n_flows), np.float32)
+                            for b, i in enumerate(idxs):
+                                xf[b, :len(x_fixed[i])] = np.asarray(
+                                    x_fixed[i], np.float32)
+                        enf = np.zeros(rows, bool)
+                        for b, sim in enumerate(chunk):
+                            enf[b] = sim.is_dynamic
                 except Exception as e:  # noqa: BLE001 — route to recovery
                     # pack failed before the slot advanced: nothing was
                     # submitted, the phase counter stays put, and the
                     # chunk re-runs synchronously on scratch buffers
-                    stage_s += time.perf_counter() - t0
                     _recover_chunk(bi, j, idxs, chunk, e)
                     continue
                 staged_n[s] += 1
-                t1 = time.perf_counter()
-                stage_s += t1 - t0
                 # overlap bookkeeping: staging is *hidden* when compute is
                 # in flight somewhere; it is *hideable* unless the pipeline
                 # had nothing it could possibly run yet (the very first
                 # chunk's stage — and nothing else — precedes all work)
                 if inflight_total:
-                    hidden_stage_s += t1 - t0
+                    hidden_stage_s += staging.seconds
                 if inflight_total or any(p is not None for p in pending):
-                    hideable_stage_s += t1 - t0
+                    hideable_stage_s += staging.seconds
                 live = sum(b.nbytes
                            for slot in self._campaign_bufs.values()
                            for b in slot.values())
@@ -1753,6 +1756,7 @@ class FleetRunner:
             error_repr = repr(e)
             raise
         finally:
+            startup.close()  # no dispatch: every chunk resumed, or failed
             # teardown runs on success AND on any failure (including
             # KeyboardInterrupt / injected aborts): cancel in-flight
             # transfers, drop uncollected dispatches, and write
@@ -1790,21 +1794,17 @@ class FleetRunner:
                 "chunk_rows": max(cap_rows),
                 "target_chunk_rows": target_rows,
                 "auto_chunk": auto_chunk,
-                "bucket_shapes": [dataclasses.astuple(s) for _, s in plan],
                 "policy": policy,
                 "peak_staged_rows": peak_rows,
                 "peak_staged_bytes": peak_bytes,
-                "stage_s": stage_s,
-                "dispatch_s": dispatch_s,
-                "transfer_s": transfer_s,
-                "transfer_wait_s": transfer_wait_s,
-                "block_s": block_s,
+                "rows_dispatched": rows_dispatched,
+                **tot,
                 "wall_s": wall_s,
                 "overlap_fraction": (hidden_stage_s / hideable_stage_s
                                      if hideable_stage_s > 0 else 1.0),
                 "transfer_overlap": (
-                    max(0.0, 1.0 - transfer_wait_s / transfer_s)
-                    if transfer_s > 0 else 0.0),
+                    max(0.0, 1.0 - tot["transfer_wait_s"] / tot["transfer_s"])
+                    if tot["transfer_s"] > 0 else 0.0),
                 "calibration": dataclasses.asdict(calib),
             }
         return CampaignResult(

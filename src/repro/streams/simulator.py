@@ -509,103 +509,104 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
     *current* routes: the SDN controller has already reprogrammed the
     switches, whatever the policy's stale rate vector was solved against.
     """
-    R = sim.R if R_t is None else R_t
-    dst, src = sim.dst_of_flow, sim.src_of_flow
+    with jax.named_scope("tick"):
+        R = sim.R if R_t is None else R_t
+        dst, src = sim.dst_of_flow, sim.src_of_flow
 
-    # receiver-window flow control: never overflow the receive buffer
-    desired = jnp.minimum(jnp.minimum(Qs, x * dt),
-                          jnp.maximum(qcap - Qr, 0.0))
-    if caps_t is None or enforce is False:
-        # static capacities: the policies' rate vectors are already
-        # link-feasible, so the transfer needs no per-tick capacity check
-        # (the pre-dynamics semantics — and cost — exactly)
-        transfer = desired
-    else:
-        # the network enforces the *current* capacity: between controller
-        # updates a failed/shrunk link moves at most caps_t·dt, whatever
-        # the stale rate vector says. Feasible loads scale by exactly 1.0,
-        # so a constant schedule reproduces the static path.
-        load0 = jnp.matmul(desired, R, precision=_HIGHEST)       # [L] MB
-        lscale = jnp.where(load0 > caps_t * dt,
-                           jnp.clip(caps_t * dt / jnp.maximum(load0, _EPS),
-                                    0.0, 1.0),
-                           1.0)
-        fscale = jnp.min(jnp.where(R > 0, lscale[None, :], jnp.inf),
-                         axis=1)
-        fscale = jnp.where(jnp.isfinite(fscale), fscale, 1.0)
-        if enforce is not True:
-            # traced per-scenario gate: un-enforced rows multiply by
-            # exactly 1.0, which is bitwise the static transfer
-            fscale = jnp.where(enforce, fscale, 1.0)
-        transfer = desired * fscale
-    Qs = Qs - transfer
-    Qr = Qr + transfer
+        # receiver-window flow control: never overflow the receive buffer
+        desired = jnp.minimum(jnp.minimum(Qs, x * dt),
+                              jnp.maximum(qcap - Qr, 0.0))
+        if caps_t is None or enforce is False:
+            # static capacities: the policies' rate vectors are already
+            # link-feasible, so the transfer needs no per-tick capacity check
+            # (the pre-dynamics semantics — and cost — exactly)
+            transfer = desired
+        else:
+            # the network enforces the *current* capacity: between controller
+            # updates a failed/shrunk link moves at most caps_t·dt, whatever
+            # the stale rate vector says. Feasible loads scale by exactly 1.0,
+            # so a constant schedule reproduces the static path.
+            load0 = jnp.matmul(desired, R, precision=_HIGHEST)       # [L] MB
+            lscale = jnp.where(load0 > caps_t * dt,
+                               jnp.clip(caps_t * dt / jnp.maximum(load0, _EPS),
+                                        0.0, 1.0),
+                               1.0)
+            fscale = jnp.min(jnp.where(R > 0, lscale[None, :], jnp.inf),
+                             axis=1)
+            fscale = jnp.where(jnp.isfinite(fscale), fscale, 1.0)
+            if enforce is not True:
+                # traced per-scenario gate: un-enforced rows multiply by
+                # exactly 1.0, which is bitwise the static transfer
+                fscale = jnp.where(enforce, fscale, 1.0)
+            transfer = desired * fscale
+        Qs = Qs - transfer
+        Qr = Qr + transfer
 
-    # --- processing ---------------------------------------------------
-    ratio = Qr / jnp.maximum(sim.p_in, _EPS)                     # [F]
-    masked = jnp.where(sim.M_in > 0, ratio[None, :], jnp.inf)    # [I, F]
-    join_amt = jnp.min(masked, axis=1)                           # [I]
-    join_amt = jnp.where(jnp.isfinite(join_amt), join_amt, 0.0)
-    join_amt = jnp.minimum(join_amt, sim.proc_rate * dt)
-    consume_join = join_amt[dst] * sim.p_in                      # [F]
+        # --- processing ---------------------------------------------------
+        ratio = Qr / jnp.maximum(sim.p_in, _EPS)                     # [F]
+        masked = jnp.where(sim.M_in > 0, ratio[None, :], jnp.inf)    # [I, F]
+        join_amt = jnp.min(masked, axis=1)                           # [I]
+        join_amt = jnp.where(jnp.isfinite(join_amt), join_amt, 0.0)
+        join_amt = jnp.minimum(join_amt, sim.proc_rate * dt)
+        consume_join = join_amt[dst] * sim.p_in                      # [F]
 
-    total_in = jnp.matmul(sim.M_in, Qr, precision=_HIGHEST)      # [I]
-    amt = jnp.minimum(total_in, sim.proc_rate * dt)
-    frac = amt / jnp.maximum(total_in, _EPS)
-    consume_any = Qr * frac[dst]
+        total_in = jnp.matmul(sim.M_in, Qr, precision=_HIGHEST)      # [I]
+        amt = jnp.minimum(total_in, sim.proc_rate * dt)
+        frac = amt / jnp.maximum(total_in, _EPS)
+        consume_any = Qr * frac[dst]
 
-    consume = jnp.where(sim.join_dst, consume_join, consume_any)
-    consume = jnp.minimum(consume, Qr)
+        consume = jnp.where(sim.join_dst, consume_join, consume_any)
+        consume = jnp.minimum(consume, Qr)
 
-    # sender-side backpressure (Storm's bounded send buffers): an instance
-    # whose outgoing queue is full stalls its processing / generation
-    in_i = jnp.matmul(sim.M_in, consume, precision=_HIGHEST)     # [I]
-    out_i = sim.selectivity * in_i + sim.gen_rate * dt
-    prod = out_i[src] * sim.w_of_flow                            # [F]
-    space = jnp.maximum(qcap - Qs, 0.0)
-    scale_f = jnp.clip(space / jnp.maximum(prod, _EPS), 0.0, 1.0)
-    # droppable (latest-value) streams never backpressure upstream: the app
-    # overwrites stale records in its send queue instead of blocking
-    stalled = jnp.where((sim.w_out > 0) & ~sim.droppable[None, :],
-                        scale_f[None, :], jnp.inf)
-    stall_i = jnp.min(stalled, axis=1)                           # [I]
-    stall_i = jnp.where(jnp.isfinite(stall_i), stall_i, 1.0)
+        # sender-side backpressure (Storm's bounded send buffers): an instance
+        # whose outgoing queue is full stalls its processing / generation
+        in_i = jnp.matmul(sim.M_in, consume, precision=_HIGHEST)     # [I]
+        out_i = sim.selectivity * in_i + sim.gen_rate * dt
+        prod = out_i[src] * sim.w_of_flow                            # [F]
+        space = jnp.maximum(qcap - Qs, 0.0)
+        scale_f = jnp.clip(space / jnp.maximum(prod, _EPS), 0.0, 1.0)
+        # droppable (latest-value) streams never backpressure upstream: the app
+        # overwrites stale records in its send queue instead of blocking
+        stalled = jnp.where((sim.w_out > 0) & ~sim.droppable[None, :],
+                            scale_f[None, :], jnp.inf)
+        stall_i = jnp.min(stalled, axis=1)                           # [I]
+        stall_i = jnp.where(jnp.isfinite(stall_i), stall_i, 1.0)
 
-    consume = consume * stall_i[dst]
-    Qr = Qr - consume
-    # stale-data discard: droppable join inputs keep only a small working
-    # window; bytes beyond it were carried by the network for nothing.
-    Qr = jnp.where(sim.droppable, jnp.minimum(Qr, 0.5), Qr)
-    in_i = in_i * stall_i        # = M_in @ (consume·stall[dst]), fused
-    out_i = sim.selectivity * in_i + sim.gen_rate * dt * stall_i
-    Qs = Qs + out_i[src] * sim.w_of_flow   # = w_out.T @ out_i, fused
-    # latest-value send queues hold only the freshest working window
-    Qs = jnp.where(sim.droppable, jnp.minimum(Qs, 0.5), Qs)
+        consume = consume * stall_i[dst]
+        Qr = Qr - consume
+        # stale-data discard: droppable join inputs keep only a small working
+        # window; bytes beyond it were carried by the network for nothing.
+        Qr = jnp.where(sim.droppable, jnp.minimum(Qr, 0.5), Qr)
+        in_i = in_i * stall_i        # = M_in @ (consume·stall[dst]), fused
+        out_i = sim.selectivity * in_i + sim.gen_rate * dt * stall_i
+        Qs = Qs + out_i[src] * sim.w_of_flow   # = w_out.T @ out_i, fused
+        # latest-value send queues hold only the freshest working window
+        Qs = jnp.where(sim.droppable, jnp.minimum(Qs, 0.5), Qs)
 
-    sink_in = jnp.where(sim.is_sink, in_i, 0.0)
-    sink_mb = jnp.sum(sink_in)
-    if sim.n_apps == 1:
-        # single-app sims (the common case): the per-app split IS the total
-        sink_mb_app = sink_mb[None]
-    else:
-        # small one-hot contraction instead of a segment_sum: under the
-        # fleet vmap this is a batched GEMM where a scatter would serialize
-        onehot = (sim.app_of_inst[None, :]
-                  == jnp.arange(sim.n_apps)[:, None]).astype(sink_in.dtype)
-        sink_mb_app = jnp.matmul(onehot, sink_in, precision=_HIGHEST)
-    drain = consume / dt                                         # [F] MB/s
+        sink_in = jnp.where(sim.is_sink, in_i, 0.0)
+        sink_mb = jnp.sum(sink_in)
+        if sim.n_apps == 1:
+            # single-app sims (the common case): the per-app split IS the total
+            sink_mb_app = sink_mb[None]
+        else:
+            # small one-hot contraction instead of a segment_sum: under the
+            # fleet vmap this is a batched GEMM where a scatter would serialize
+            onehot = (sim.app_of_inst[None, :]
+                      == jnp.arange(sim.n_apps)[:, None]).astype(sink_in.dtype)
+            sink_mb_app = jnp.matmul(onehot, sink_in, precision=_HIGHEST)
+        drain = consume / dt                                         # [F] MB/s
 
-    # --- latency estimate (per source→sink path) ----------------------
-    # raw per-flow waits only; the path-mean contraction (path_w · wait)
-    # happens host-side on the true [F] slice, so the reported latency is
-    # bitwise-identical however the fleet engine pads/packs the flow axis
-    wait = jnp.minimum(
-        Qs / jnp.maximum(x, _EPS) + Qr / jnp.maximum(drain, _EPS), _LAT_CAP
-    )
+        # --- latency estimate (per source→sink path) ----------------------
+        # raw per-flow waits only; the path-mean contraction (path_w · wait)
+        # happens host-side on the true [F] slice, so the reported latency is
+        # bitwise-identical however the fleet engine pads/packs the flow axis
+        wait = jnp.minimum(
+            Qs / jnp.maximum(x, _EPS) + Qr / jnp.maximum(drain, _EPS), _LAT_CAP
+        )
 
-    link_load = jnp.matmul(transfer, R,
-                           precision=_HIGHEST) / dt              # [L] MB/s
-    return Qs, Qr, transfer, drain, (sink_mb, sink_mb_app, wait, link_load)
+        link_load = jnp.matmul(transfer, R,
+                               precision=_HIGHEST) / dt              # [L] MB/s
+        return Qs, Qr, transfer, drain, (sink_mb, sink_mb_app, wait, link_load)
 
 
 # --------------------------------------------------------------------------

@@ -107,3 +107,11 @@ def test_tcp_contractions_run_at_highest(campaign_texts):
     loose = [ln.strip()[:160] for ln in lines
              if "operand_precision={highest,highest}" not in ln]
     assert not loose, loose
+
+
+def test_tcp_chunk_names_its_scopes(campaign_texts):
+    # a chip trace names each operation by its op_name: the max-min solve
+    # and the fluid tick keep their scopes through the TPU compiler
+    text = campaign_texts("tcp")
+    assert "/maxmin/" in text
+    assert "/tick/" in text
