@@ -202,6 +202,16 @@ def _solve_link_block(mask, cap, kind, ctx, dt: float):
     return jnp.where(is_down, x_dn, x_up)
 
 
+def _min_over_links(rows, mask, kind):
+    """Alg. 1 line 22 collapsed: min(x^u, x^d) over a flow's links is the
+    min of the per-link rows [L, F] over its non-internal links (each row
+    already carries the kind-appropriate solve), so one masked reduction
+    replaces the two per-kind passes. mask: [L, F] on-link (> 0); kind:
+    [L]. Returns [F], +inf for a flow on no such link."""
+    sel = (kind != int(LinkKind.INTERNAL))[:, None] & (mask > 0)
+    return jnp.min(jnp.where(sel, rows, _INF), axis=0)
+
+
 def _per_link_rates(program: LinkProgram, state: FlowState, dt: float):
     """Fused batched [L, F] solve of eqs. (3) and (4) for every link at
     once: one global argsort (:func:`_flow_sort_ctx`) + one
@@ -213,35 +223,40 @@ def _per_link_rates(program: LinkProgram, state: FlowState, dt: float):
 
 def _per_link_rates_chunked(program: LinkProgram, state: FlowState,
                             dt: float, block_links: int):
-    """Chunked-links variant of the fused solve: the same
-    :func:`_solve_link_block` math, but the link axis is processed in
-    ``block_links`` chunks under ``lax.map`` (sequential), so the [L, F]
-    intermediates (masked cumsums, candidate levels, prefix selections)
-    are capped at [block_links, F] — at 10⁴ links × 10³ flows that's the
-    difference between ~40 MB per intermediate and ~4 MB total working
-    set. Only the [L, F] *output* (and the input routing matrix) stay
-    full-size. The flow context (one global argsort) is shared across
-    chunks, exactly as in the fused form.
+    """Chunked-links variant of the fused solve, with Alg. 1 line 22 folded
+    in: the same :func:`_solve_link_block` math, but the link axis is
+    processed in ``block_links`` chunks under ``lax.scan`` (sequential),
+    and each block is reduced by :func:`_min_over_links` into a running
+    per-flow minimum. Returns x [F], the line-22 rates, equal bitwise to
+    the combine of the full-axis rows (min is exact in any order).
+
+    Only [block_links, F] solver values exist: no per-link [L, F] rows
+    are stacked or read back — at 10⁴ links × 10³ flows that's ~4 MB of
+    working set instead of a ~40 MB output written block by block. The
+    routing matrix stays full-size as the input. The flow context (one
+    global argsort) is shared across chunks, exactly as in the fused form.
     """
     L, F = program.R.shape[1], program.R.shape[0]
     ctx = _flow_sort_ctx(state, dt)
 
-    def chunk(args):
+    def chunk(x, args):
         mask, cap, kind = args                      # [blk, F], [blk], [blk]
-        return _solve_link_block(mask, cap, kind, ctx, dt)
+        rows = _solve_link_block(mask, cap, kind, ctx, dt)
+        return jnp.minimum(x, _min_over_links(rows, mask, kind)), None
 
     blk = max(int(block_links), 1)
     n_chunks = -(-L // blk)
     pad = n_chunks * blk - L
-    # padded links: empty mask, INTERNAL kind -> all-zero rows, dropped below
+    # padded links: empty mask, INTERNAL kind -> never selected by the min
     maskT = jnp.pad((program.R.T > 0).astype(jnp.float32), ((0, pad), (0, 0)))
     cap_p = jnp.pad(program.capacity, (0, pad))
     kind_p = jnp.pad(program.kind, (0, pad),
                      constant_values=int(LinkKind.INTERNAL))
-    rows = jax.lax.map(chunk, (maskT.reshape(n_chunks, blk, F),
-                               cap_p.reshape(n_chunks, blk),
-                               kind_p.reshape(n_chunks, blk)))
-    return rows.reshape(n_chunks * blk, F)[:L]
+    x, _ = jax.lax.scan(chunk, jnp.full((F,), _INF, maskT.dtype),
+                        (maskT.reshape(n_chunks, blk, F),
+                         cap_p.reshape(n_chunks, blk),
+                         kind_p.reshape(n_chunks, blk)))
+    return x
 
 
 def _per_link_rates_pallas(program: LinkProgram, state: FlowState, dt: float):
@@ -313,37 +328,33 @@ def allocate(
             "pallas" — the batched bisection waterfill kernel (TPU-friendly;
             interpret mode off-TPU). Both satisfy the same KKT conditions.
     block_links: with the "sort" solver, process links in chunks of this
-            size (sequential ``lax.map``), capping the [L, F] solver
-            intermediates — exact same results, bounded working set at
-            datacenter link counts (ignored by "pallas", which tiles
-            internally). ``None`` (the default) dispatches at trace time
-            on the static link count: single-pass below
-            ``2 * ALLOC_BLOCK_LINKS`` links (every simulator topology —
-            the fused form's XLA program is unchanged there), chunks of
-            ``ALLOC_BLOCK_LINKS`` above it. Pass ``0`` to force the
-            single-pass form at any size.
+            size (sequential ``lax.scan``) and fold the line-22 min into
+            the loop, so only [block_links, F] solver values exist —
+            exact same results, bounded working set at datacenter link
+            counts (ignored by "pallas", which tiles internally). ``None``
+            (the default) dispatches at trace time on the static link
+            count: single-pass below ``2 * ALLOC_BLOCK_LINKS`` links
+            (every simulator topology — the fused form's XLA program is
+            unchanged there), chunks of ``ALLOC_BLOCK_LINKS`` above it.
+            Pass ``0`` to force the single-pass form at any size.
     """
+    kind = program.kind
+    per_link = None                     # [L, F] rows of the unchunked paths
     with jax.named_scope("per_link"):
         if solver == "sort":
             if block_links is None and program.R.shape[1] > 2 * ALLOC_BLOCK_LINKS:
                 block_links = ALLOC_BLOCK_LINKS
-            if block_links:
-                per_link = _per_link_rates_chunked(program, state, dt,
-                                                   block_links)   # [L, F]
+            if block_links:                 # line 22 runs inside the loop
+                x = _per_link_rates_chunked(program, state, dt,
+                                            block_links)           # [F]
             else:
                 per_link = _per_link_rates(program, state, dt)     # [L, F]
         elif solver == "pallas":
             per_link = _per_link_rates_pallas(program, state, dt)  # [L, F]
         else:
             raise ValueError(f"unknown solver {solver!r}")
-    kind = program.kind
-
-    # Alg. 1 line 22 collapsed: min(x^u, x^d) over a flow's links is the min
-    # of per_link over its non-internal links (each row already carries the
-    # kind-appropriate solve), so one masked reduction replaces the two
-    # per-kind passes.
-    sel = (kind != int(LinkKind.INTERNAL))[:, None] & (program.R.T > 0)
-    x = jnp.min(jnp.where(sel, per_link, _INF), axis=0)
+    if per_link is not None:
+        x = _min_over_links(per_link, program.R.T, kind)           # line 22
     x = jnp.where(jnp.isfinite(x), x, 0.0)     # flows with no links: handled by caller
 
     # Internal links: proportional scale-down, min across links (lines 24-29)

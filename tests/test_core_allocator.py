@@ -16,7 +16,9 @@ from repro.core import (
     ewma_throughput,
 )
 from repro.core.allocator import (
+    ALLOC_BLOCK_LINKS,
     LinkProgram,
+    _min_over_links,
     _per_link_rates,
     _per_link_rates_vmap,
     allocate,
@@ -243,9 +245,15 @@ class TestFusedPerLinkRates:
 # ----------------------------------------------------- chunked-links solve
 class TestChunkedPerLinkRates:
     """``allocate(..., block_links=k)`` processes the link axis in chunks
-    (bounded [block, F] intermediates) and must reproduce the fused solve
-    exactly — including block sizes that don't divide L, exceed L, or
-    degenerate to one link per chunk."""
+    (bounded [block, F] intermediates, the line-22 min carried through the
+    loop) and must reproduce the fused solve exactly — including block
+    sizes that don't divide L, exceed L, or degenerate to one link per
+    chunk."""
+
+    @staticmethod
+    def _fused_line22(prog, state, dt):
+        rows = _per_link_rates(prog, state, dt)                  # [L, F]
+        return np.asarray(_min_over_links(rows, prog.R.T, prog.kind))
 
     @pytest.mark.parametrize("blk", [1, 7, 16, 64])
     def test_parity_vs_fused(self, blk):
@@ -255,8 +263,9 @@ class TestChunkedPerLinkRates:
         F, L = 40, 37
         prog = _rand_program(rng, F, L, p=0.3)
         state = _rand_flowstate(rng, F)
-        a = np.asarray(_per_link_rates(prog, state, 5.0))
+        a = self._fused_line22(prog, state, 5.0)
         b = np.asarray(_per_link_rates_chunked(prog, state, 5.0, blk))
+        assert b.shape == (F,)
         np.testing.assert_allclose(a, b, atol=1e-5)
 
     @settings(max_examples=15, deadline=None)
@@ -283,7 +292,21 @@ class TestChunkedPerLinkRates:
         state = FlowState(z, z, z, z, z)
         np.testing.assert_allclose(
             np.asarray(_per_link_rates_chunked(prog, state, 0.5, 4)),
-            np.asarray(_per_link_rates(prog, state, 0.5)), atol=1e-5)
+            self._fused_line22(prog, state, 0.5), atol=1e-5)
+
+    def test_auto_chunked_allocate_bitwise(self):
+        # above 2 * ALLOC_BLOCK_LINKS links the default dispatches to the
+        # chunked loop; min is exact, so it equals the single pass bitwise
+        rng = np.random.default_rng(13)
+        F, L = 64, 1100
+        assert L > 2 * ALLOC_BLOCK_LINKS
+        prog = _rand_program(rng, F, L, p=0.01)
+        assert set(np.unique(np.asarray(prog.kind))) == {
+            int(k) for k in LinkKind}
+        state = _rand_flowstate(rng, F)
+        auto = np.asarray(allocate(prog, state, dt=1.0, block_links=None))
+        single = np.asarray(allocate(prog, state, dt=1.0, block_links=0))
+        assert np.array_equal(auto, single)
 
 
 # ------------------------------------------------------------- Algorithm 1

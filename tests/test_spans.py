@@ -115,6 +115,21 @@ def test_allocate_names_its_scopes(solver, block_links):
     assert "/backfill/" in text
 
 
+def test_chunked_allocate_stacks_no_link_rows():
+    # the chunked solve carries the per-flow line-22 min through its loop:
+    # no block of per-link rows is written into a stacked [L, F] output
+    F, L = 16, 40
+    rng = np.random.default_rng(2)
+    R = (rng.uniform(size=(F, L)) < 0.2).astype(np.float32)
+    alloc = OnlineAllocator(R, np.full(L, 100.0), np.arange(L) % 3)
+    vec = jax.ShapeDtypeStruct((F,), jnp.float32)
+    text = allocate.lower(
+        alloc.program, FlowState(vec, vec, vec, vec, vec), dt=1.0,
+        backfill_iters=8, block_links=8).compile().as_text()
+    assert "/per_link/" in text
+    assert "dynamic-update-slice" not in text
+
+
 def test_campaign_program_names_its_scopes():
     sims = compile_fleet(campaign_fleet(12, seed=0))
     runner = FleetRunner()
