@@ -7,7 +7,14 @@ per-layer metric sits in a file of its own, found by the name that
 * configuration ``<c>``: the ``file`` of its entry (``bench/configs/<c>.json``);
 * traffic mix ``<t>``: ``bench/traffic/<t>.json``;
 * per-layer metric ``<m>``: ``bench/metrics/<m>.py``, a module with
-  ``read(ctx) -> float | None``.
+  ``read(ctx) -> float | None``;
+* the loop of traffic kind ``<k>`` (the traffic's ``kind``):
+  ``bench/loops/<k>.py``, a module with ``run(cell, args, t_start,
+  devices, hooks)``, ``control(cell, seed)`` and ``LAYERS``, the layers
+  its per-layer metrics may name;
+* the deployment ``<d>`` (the configuration's ``deployment``):
+  ``bench/deployments/<d>.py``, a module with what its loops call of it
+  (each loop's docstring lists that).
 
 Adding any of them is adding a file; no file here changes.
 """
@@ -17,6 +24,9 @@ import dataclasses
 import importlib.util
 import json
 import os
+import re
+import sys
+import types
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -30,6 +40,7 @@ class Cell:
     traffic: dict
     end_to_end: list[dict]     # metric entries the cell reports, in order
     per_layer: list[dict]
+    deployment: types.ModuleType   # bench/deployments/<config's deployment>
 
 
 def load_benchmark(root: str = ROOT) -> dict:
@@ -49,6 +60,31 @@ def metric_path(name: str, root: str = ROOT) -> str:
     return os.path.join(root, "bench", "metrics", f"{name}.py")
 
 
+def loop_path(kind: str, root: str = ROOT) -> str:
+    return os.path.join(root, "bench", "loops", f"{kind}.py")
+
+
+def deployment_path(name: str, root: str = ROOT) -> str:
+    return os.path.join(root, "bench", "deployments", f"{name}.py")
+
+
+def load_file(path: str, what: str) -> types.ModuleType:
+    """The module in file ``path`` (``what`` it is, for the error a
+    missing file raises), executed afresh under a name made of its
+    directory and file name, and registered, so that its dataclasses
+    resolve their annotations."""
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"{what} has no file {path}")
+    stem = os.path.splitext(os.path.basename(path))[0]
+    modname = re.sub(r"\W", "_", "bench_" + os.path.basename(
+        os.path.dirname(path)) + "_" + stem)
+    spec = importlib.util.spec_from_file_location(modname, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[modname] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def resolve(workload: str, root: str = ROOT, bench: dict | None = None
             ) -> Cell:
     bench = load_benchmark(root) if bench is None else bench
@@ -65,14 +101,21 @@ def resolve(workload: str, root: str = ROOT, bench: dict | None = None
     return Cell(
         name=workload, chips=int(w["chips"]), config=config, traffic=traffic,
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
-        per_layer=[m for m in bench["per_layer"] if _applies(m, workload)])
+        per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
+        deployment=load_deployment(config["deployment"], root))
 
 
 def load_reader(name: str, root: str = ROOT):
     """The ``read`` function of per-layer metric ``name``."""
-    path = metric_path(name, root)
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(metric_path(name, root),
+                     f"per-layer metric {name!r}").read
+
+
+def load_loop(kind: str, root: str = ROOT) -> types.ModuleType:
+    """The loop module of traffic kind ``kind``."""
+    return load_file(loop_path(kind, root), f"traffic kind {kind!r}")
+
+
+def load_deployment(name: str, root: str = ROOT) -> types.ModuleType:
+    """The deployment module ``name`` of a configuration."""
+    return load_file(deployment_path(name, root), f"deployment {name!r}")

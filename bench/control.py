@@ -10,7 +10,8 @@ cell's own numbers against the float64 reference.
 Prints one JSON line per seed with each number compared beside its limit;
 a sound check reads above the limit on every seed. Runs on the host (the
 reference and its control are numpy); the scenarios are built and
-compiled through the program as in a run.
+compiled through the program as in a run. Each kind of traffic's control
+is the ``control(cell, seed)`` of its loop (``bench/loops/<kind>.py``).
 """
 import argparse
 import json
@@ -22,48 +23,6 @@ ROOT = os.path.dirname(HERE)
 sys.path[:0] = [HERE, os.path.join(ROOT, "src")]
 
 
-def campaign_control(cell, seed: int) -> dict:
-    """The control's rows of the cell's own sample: one scenario of every
-    chunk of the plan this machine's planner makes for the corpus."""
-    from repro.streams import FleetRunner
-
-    from benchlib import campaign, deploy, reference
-    policy = cell.traffic["policy"]
-    kw = campaign.settings(cell.config, policy)
-    corpus = deploy.testbed_corpus(cell.config, seed)
-    plan = FleetRunner().plan(
-        [deploy.program_scenario(sc).compile() for sc in corpus], policy)
-    chosen = [corpus[i] for i in campaign.sample(plan, kw["chunk_rows"], seed)]
-    n_ticks = int(round(kw["seconds"] / kw["dt"]))
-    rows = [reference.simulate_ref(
-                reference.testbed_arrays(sc.graph, sc.placement,
-                                         sc.n_machines, sc.cap, sc.events,
-                                         sc.diurnal),
-                policy, n_ticks, kw["dt"], kw["upd_every"], kw["qcap"],
-                reference.Arith("high"))[None]
-            for sc in chosen]
-    checks, _ = campaign.compare(chosen, rows, policy, kw,
-                                 cell.traffic["limits"])
-    return checks
-
-
-def controller_control(cell, seed: int) -> dict:
-    from benchlib import controller, deploy, reference
-    cfg, tr = cell.config, cell.traffic
-    fab = deploy.fabric(cfg, seed)
-    states = deploy.flow_states(cfg, fab, int(tr["n_states"]),
-                                int(tr["warm_intervals"]))
-    dt = float(cfg["controller_interval_s"])
-    iters = int(cfg["backfill_iters"])
-    answers = [reference.allocate_ref(fab.R, fab.cap, fab.kind, st, dt,
-                                      reference.Arith("high"),
-                                      backfill_iters=iters)
-               for st in states]
-    checks, _ = controller.compare(fab, states, answers, dt, iters,
-                                   tr["limits"])
-    return checks
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -71,11 +30,10 @@ def main() -> int:
     a = ap.parse_args()
     from benchlib import spec
     cell = spec.resolve(a.workload)
-    fn = {"campaign": campaign_control,
-          "controller": controller_control}[cell.traffic["kind"]]
+    loop = spec.load_loop(cell.traffic["kind"])
     failed_all = True
     for seed in (int(s) for s in a.seeds.split(",")):
-        checks = fn(cell, seed)
+        checks = loop.control(cell, seed)
         failed = not all(c["ok"] for c in checks.values())
         failed_all &= failed
         print(json.dumps({"workload": a.workload, "seed": seed,

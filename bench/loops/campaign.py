@@ -3,17 +3,25 @@
 
 The window ends when the campaign running at ``--seconds`` completes;
 the rate is every scenario completed over the time from the window's
-start to that end."""
+start to that end.
+
+What it calls of the cell's deployment module: ``corpus(cfg, seed)``, a
+list of scenarios, each with a ``name``; ``program_scenario(sc)``, the
+program's ``Scenario`` of one; ``reference_row(sc, policy, kw,
+precision="exact")``, the plain reference's metric row of one
+([7], ``reference.METRICS``), with ``kw`` from :func:`settings` and
+``precision`` "exact" or "high" (``reference.Arith``)."""
 from __future__ import annotations
 
 import dataclasses
 
 import numpy as np
 
-from benchlib import deploy, reference
+from benchlib import reference
 from benchlib.harness import (CompileWatch, GuardError, TracedWindow,
                               check, device_record, log, now, span)
 
+LAYERS = ("campaign pipeline", "scan program", "device")
 SPANS = ("stage_s", "transfer_s", "transfer_wait_s", "dispatch_s", "block_s")
 
 
@@ -48,11 +56,11 @@ def settings(cfg: dict, policy: str) -> dict:
 def run(cell, args, t_start: float, devices, hooks: dict) -> tuple:
     from repro.streams import FleetRunner
 
-    cfg, tr = cell.config, cell.traffic
+    cfg, tr, dep = cell.config, cell.traffic, cell.deployment
     policy = tr["policy"]
     kw = settings(cfg, policy)
-    corpus = deploy.testbed_corpus(cfg, args.seed)
-    sims = [deploy.program_scenario(sc).compile() for sc in corpus]
+    corpus = dep.corpus(cfg, args.seed)
+    sims = [dep.program_scenario(sc).compile() for sc in corpus]
     runner = FleetRunner()
     campaign = hooks.get("wrap_campaign", lambda f: f)(runner.run_campaign)
     plan = runner.plan(sims, policy)
@@ -107,10 +115,29 @@ def run(cell, args, t_start: float, devices, hooks: dict) -> tuple:
            "device_kind": devices[0].device_kind}
 
     idx = sample(plan, kw["chunk_rows"], args.seed)
-    checks, n_bad = compare([corpus[i] for i in idx],
+    checks, n_bad = compare(dep, [corpus[i] for i in idx],
                             [np.stack([m[i] for m in results]) for i in idx],
                             policy, kw, tr["limits"])
     return e2e, ctx, checks, dev, n_done, n_bad
+
+
+def control(cell, seed: int) -> dict:
+    """The checks of the control: the plain reference at ``high`` put in
+    the program's place for the cell's own sample, one scenario of every
+    chunk of the plan this machine's planner makes for the corpus."""
+    from repro.streams import FleetRunner
+
+    dep = cell.deployment
+    policy = cell.traffic["policy"]
+    kw = settings(cell.config, policy)
+    corpus = dep.corpus(cell.config, seed)
+    plan = FleetRunner().plan(
+        [dep.program_scenario(sc).compile() for sc in corpus], policy)
+    chosen = [corpus[i] for i in sample(plan, kw["chunk_rows"], seed)]
+    rows = [dep.reference_row(sc, policy, kw, "high")[None]
+            for sc in chosen]
+    checks, _ = compare(dep, chosen, rows, policy, kw, cell.traffic["limits"])
+    return checks
 
 
 def chunks(plan, chunk_rows: int) -> list[list[int]]:
@@ -166,24 +193,19 @@ def row_gap(rows: np.ndarray, ref: np.ndarray, horizon_s: float
     return column_gaps(rows, ref, horizon_s).max(axis=1)
 
 
-def compare(scenarios: list, rows: list, policy: str, kw: dict,
+def compare(dep, scenarios: list, rows: list, policy: str, kw: dict,
             limits: dict):
     """The sampled scenarios' rows from every window campaign against the
-    reference run of each scenario, built from the scenario's own
-    parameters. Two numbers: the widest gap of any sampled row in any
-    campaign, which a broken, misplaced or altered row moves; and the
-    median over the sampled scenarios of each one's widest gap, which a
-    precision step down moves (float32 rounding alone already takes a
-    few scenarios' widest gap as far as the step down does)."""
-    ar = reference.Arith("exact")
-    n_ticks = int(round(kw["seconds"] / kw["dt"]))
+    reference run of each scenario (the deployment ``dep``'s
+    ``reference_row``), built from the scenario's own parameters. Two
+    numbers: the widest gap of any sampled row in any campaign, which a
+    broken, misplaced or altered row moves; and the median over the
+    sampled scenarios of each one's widest gap, which a precision step
+    down moves (float32 rounding alone already takes a few scenarios'
+    widest gap as far as the step down does)."""
     worst, cols = [], []
     for sc, r in zip(scenarios, rows):
-        s = reference.testbed_arrays(sc.graph, sc.placement, sc.n_machines,
-                                     sc.cap, sc.events, sc.diurnal)
-        ref = reference.simulate_ref(s, policy, n_ticks, kw["dt"],
-                                     kw["upd_every"], kw["qcap"], ar)
-        g = column_gaps(r, ref, kw["seconds"])
+        g = column_gaps(r, dep.reference_row(sc, policy, kw), kw["seconds"])
         worst.append(float(g.max()))
         cols.append(g.max(axis=0))
     worst = np.asarray(worst)

@@ -1,13 +1,21 @@
 """Runs controller cells: a closed loop of control intervals, each
 one ``OnlineAllocator`` call from a host-side flow state to the rates
-back on the host."""
+back on the host.
+
+What it calls of the cell's deployment module: ``fabric(cfg, seed,
+traffic)``, a ``benchlib.deploy.Fabric`` (R, cap, kind); and
+``flow_states(cfg, fab, traffic)``, the traffic's ``n_states`` flow
+states, each the five FlowState fields ([F] float32). The configuration
+gives ``controller_interval_s`` and ``backfill_iters``."""
 from __future__ import annotations
 
 import numpy as np
 
-from benchlib import deploy, reference
+from benchlib import reference
 from benchlib.harness import (CompileWatch, GuardError, TracedWindow,
                               check, device_record, log, now, span)
+
+LAYERS = ("controller solve", "device")
 
 # a traced run traces the window's first seconds only: each solve runs
 # some thousand device operations, and a trace of the whole window would
@@ -19,15 +27,14 @@ def run(cell, args, t_start: float, devices, hooks: dict) -> tuple:
     from repro.core.allocator import OnlineAllocator
     from repro.core.flowstate import FlowState
 
-    cfg, tr = cell.config, cell.traffic
+    cfg, tr, dep = cell.config, cell.traffic, cell.deployment
     dt = float(cfg["controller_interval_s"])
     iters = int(cfg["backfill_iters"])
-    fab = deploy.fabric(cfg, args.seed)
+    fab = dep.fabric(cfg, args.seed, tr)
     alloc = OnlineAllocator(fab.R, fab.cap, fab.kind, dt=dt,
                             backfill_iters=iters)
     solve = hooks.get("wrap_solve", lambda f: f)(alloc)
-    states = deploy.flow_states(cfg, fab, int(tr["n_states"]),
-                                int(tr["warm_intervals"]))
+    states = dep.flow_states(cfg, fab, tr)
     nnz, (F, L) = int(np.count_nonzero(fab.R)), fab.R.shape
     log(f"controller: L={L} F={F} nnz(R)={nnz} states={len(states)} "
         f"dt={dt} s, solver {alloc.solver}")
@@ -79,6 +86,22 @@ def run(cell, args, t_start: float, devices, hooks: dict) -> tuple:
 
     checks, n_bad = compare(fab, states, answers, dt, iters, tr["limits"])
     return e2e, ctx, checks, dev, len(answers), n_bad
+
+
+def control(cell, seed: int) -> dict:
+    """The checks of the control: the plain reference at ``high`` put in
+    the program's place for every flow state of the cell."""
+    cfg, tr, dep = cell.config, cell.traffic, cell.deployment
+    fab = dep.fabric(cfg, seed, tr)
+    states = dep.flow_states(cfg, fab, tr)
+    dt = float(cfg["controller_interval_s"])
+    iters = int(cfg["backfill_iters"])
+    answers = [reference.allocate_ref(fab.R, fab.cap, fab.kind, st, dt,
+                                      reference.Arith("high"),
+                                      backfill_iters=iters)
+               for st in states]
+    checks, _ = compare(fab, states, answers, dt, iters, tr["limits"])
+    return checks
 
 
 def compare(fab, states, answers, dt, backfill_iters, limits):

@@ -57,9 +57,10 @@ def main(argv=None, hooks: dict | None = None, t_start: float = T_START,
     if os.environ.get("REPRO_SMOKE", "").strip() not in ("", "0"):
         harness.log("bench: REPRO_SMOKE caps the simulated horizon; unset it")
         return 2
-    cell = spec.resolve(args.workload, root)
+    cell = spec.resolve(args.workload, root, hooks.get("benchmark"))
     cell.config.update(hooks.get("config", {}))
     cell.traffic.update(hooks.get("traffic", {}))
+    loop = spec.load_loop(cell.traffic["kind"], root)
     src = os.path.join(root, "src")
     if src not in sys.path:
         sys.path.insert(0, src)
@@ -81,9 +82,7 @@ def main(argv=None, hooks: dict | None = None, t_start: float = T_START,
                 f"{devices[0].platform} {devices[0].device_kind} "
                 f"x{len(devices)}, jax {jax.__version__}")
 
-    from benchlib import campaign, controller, report
-    loop = {"campaign": campaign, "controller": controller}[
-        cell.traffic["kind"]]
+    from benchlib import report
     try:
         e2e, ctx, checks, dev, attempted, failed = loop.run(
             cell, args, t_start, devices, hooks)

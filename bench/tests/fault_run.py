@@ -4,15 +4,15 @@ fault planted in its timed path, and print the run's result line.
 
     python3 bench/tests/fault_run.py <cell> <fault> [--seed N]
 
-Faults: ``none``; ``state_unchanged`` (a step returns its state: the
-simulator's tick moves nothing, or the allocator's backfill returns its
-input); ``half_batch`` and ``quarter_batch`` (the last half or quarter of
-the answers left out, filled with the mean of the rest); ``one_bucket``
-and ``one_chunk`` (the rows of one bucket of the campaign's plan, or of
-one of its chunks, each written to its neighbour's place);
-``answer_altered`` (an answer changed where it is produced). The chip
-check of the harness is skipped; everything else of a run is driven as
-on the chip.
+Faults (``bench/tests/faults/<kind>.py``): ``none``; ``state_unchanged``
+(a step returns its state: the simulator's tick moves nothing, or the
+allocator's backfill returns its input); ``half_batch`` and
+``quarter_batch`` (the last half or quarter of the answers left out,
+filled with the mean of the rest); ``one_bucket`` and ``one_chunk`` (the
+rows of one bucket of the campaign's plan, or of one of its chunks, each
+written to its neighbour's place); ``answer_altered`` (an answer changed
+where it is produced). The chip check of the harness is skipped;
+everything else of a run is driven as on the chip.
 """
 import time
 
@@ -22,87 +22,43 @@ import argparse  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
-import numpy as np  # noqa: E402
-
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH, os.path.join(ROOT, "src")]
 
-SMALL = {"campaign": {"config": {"n_scenarios": 12, "horizon_s": 60.0,
-                                  "chunk_rows": 2}},
-         "controller": {"traffic": {"n_states": 2}}}
+
+# cells whose files are here though BENCHMARK.json does not hold them,
+# each with the cell whose metrics it would report
+CANDIDATES = (({"name": "testbed.controller", "config": "storm-testbed-8",
+                "traffic": "controller-testbed", "chips": 1},
+               "fattree.controller"),)
 
 
-def _campaign_faults(fault: str, hooks: dict) -> None:
-    import jax.numpy as jnp
+def benchmark() -> dict:
+    """``BENCHMARK.json`` with the ``CANDIDATES`` added, each reporting
+    the metrics of its named cell."""
+    from benchlib import spec
 
-    from repro.streams import simulator
-
-    if fault == "state_unchanged":
-        def tick(sim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
-                 R_t=None):
-            z = jnp.zeros_like(Qs)
-            L = sim.R.shape[1]
-            return Qs, Qr, z, z, (jnp.zeros(()), jnp.zeros((1,)), z,
-                                  jnp.zeros((L,)))
-        simulator._tick = tick
-    elif fault == "answer_altered":
-        epilogue = simulator._metrics_epilogue
-
-        def altered(*a, **k):
-            m = epilogue(*a, **k)
-            return m.at[0].multiply(1.0 + 1e-4)
-        simulator._metrics_epilogue = altered
-    elif fault in ROW_FAULTS:
-        def wrap(run_campaign):
-            def run(sims, policy, **k):
-                cr = run_campaign(sims, policy, **k)
-                plan = run_campaign.__self__.plan(sims, policy)
-                break_rows(cr.metrics, fault, plan, k["chunk_rows"])
-                return cr
-            return run
-        hooks["wrap_campaign"] = wrap
-    elif fault != "none":
-        raise ValueError(fault)
+    bench = spec.load_benchmark(ROOT)
+    for w, like in CANDIDATES:
+        bench["workloads"].append(dict(w))
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if like in m.get("workloads", ()):
+                m["workloads"].append(w["name"])
+    return bench
 
 
-ROW_FAULTS = ("half_batch", "quarter_batch", "one_bucket", "one_chunk")
+def faults(kind: str, root: str = ROOT):
+    """The fault module of traffic kind ``kind``:
+    ``bench/tests/faults/<kind>.py``, with ``SMALL`` (the run's size
+    overrides), ``FAULTS`` (what the kind's cells can have) and
+    ``plant(fault, hooks)``."""
+    from benchlib import spec
 
-
-def break_rows(m, fault: str, plan, chunk_rows: int) -> None:
-    """Plant one of ``ROW_FAULTS`` in a campaign's metric slab ([n, 7])."""
-    from benchlib import campaign
-
-    n = m.shape[0]
-    if fault in ("half_batch", "quarter_batch"):
-        lo = n // 2 if fault == "half_batch" else n - n // 4
-        m[lo:] = m[:lo].mean(axis=0)
-        return
-    rows = (plan[-1][0] if fault == "one_bucket"
-            else campaign.chunks(plan, chunk_rows)[-1])
-    rows = np.asarray(rows)
-    m[rows] = m[np.roll(rows, 1)]
-
-
-def _controller_faults(fault: str, hooks: dict) -> None:
-    from repro.core import allocator
-
-    if fault == "state_unchanged":
-        allocator.backfill = lambda x, program, iters=8, damping=0.9: x
-    elif fault in ("half_batch", "answer_altered"):
-        def wrap(solve):
-            def run(state):
-                x = np.array(solve(state))
-                if fault == "half_batch":
-                    x[x.shape[0] // 2:] = x[:x.shape[0] // 2].mean()
-                else:
-                    x[np.argmax(x)] *= 1.0 + 1e-3
-                return x
-            return run
-        hooks["wrap_solve"] = wrap
-    elif fault != "none":
-        raise ValueError(fault)
+    return spec.load_file(os.path.join(root, "bench", "tests", "faults",
+                                       f"{kind}.py"),
+                          f"the faults of traffic kind {kind!r}")
 
 
 def main() -> int:
@@ -114,10 +70,10 @@ def main() -> int:
     import run
     from benchlib import spec
 
-    kind = spec.resolve(a.cell, ROOT).traffic["kind"]
-    hooks = {"require_chip": False, **SMALL[kind]}
-    (_campaign_faults if kind == "campaign" else _controller_faults)(
-        a.fault, hooks)
+    bench = benchmark()
+    mod = faults(spec.resolve(a.cell, ROOT, bench).traffic["kind"])
+    hooks = {"require_chip": False, "benchmark": bench, **mod.SMALL}
+    mod.plant(a.fault, hooks)
     return run.main(["--workload", a.cell, "--seed", str(a.seed),
                      "--seconds", "0.6"], hooks=hooks, t_start=T0)
 

@@ -1,11 +1,16 @@
 """The deployments the configurations describe, built from a seed."""
 import dataclasses
+import hashlib
 
 import numpy as np
+import pytest
 
 from benchlib import deploy, reference, spec
+from fault_run import benchmark
 
 BIG_SEED = 2**40 + 123
+TESTBED = spec.load_deployment("testbed_campaign")
+FABRIC = spec.load_deployment("fabric_controller")
 
 
 def _cfg(name):
@@ -13,11 +18,22 @@ def _cfg(name):
                          "fattree-k16": "fattree.controller"}[name]).config
 
 
+def _traffic(cell, **kw):
+    return dict(spec.resolve(cell).traffic, **kw)
+
+
+def _testbed_controller():
+    """The paper's testbed under the controller loop: a cell that
+    BENCHMARK.json does not hold (its host latency spreads too widely
+    between processes for the controller bounds), built from its files."""
+    return spec.resolve("testbed.controller", bench=benchmark())
+
+
 def test_storm_testbed_2048_scenarios_in_6_shapes():
     from repro.streams.fleet import _sim_shape
 
-    sims = [deploy.program_scenario(sc).compile() for sc in
-            deploy.testbed_corpus(_cfg("storm-testbed-8"), BIG_SEED)]
+    sims = [TESTBED.program_scenario(sc).compile() for sc in
+            TESTBED.corpus(_cfg("storm-testbed-8"), BIG_SEED)]
     assert len(sims) == 2048
     shapes = {dataclasses.astuple(_sim_shape(s)) for s in sims}
     assert len(shapes) == 6
@@ -26,9 +42,9 @@ def test_storm_testbed_2048_scenarios_in_6_shapes():
 
 def test_storm_testbed_seed_moves_jitter_not_sizes():
     cfg = dict(_cfg("storm-testbed-8"), n_scenarios=36)
-    a = deploy.testbed_corpus(cfg, 7)
-    b = deploy.testbed_corpus(cfg, 7)
-    c = deploy.testbed_corpus(cfg, BIG_SEED)
+    a = TESTBED.corpus(cfg, 7)
+    b = TESTBED.corpus(cfg, 7)
+    c = TESTBED.corpus(cfg, BIG_SEED)
     for x, y, z in zip(a, b, c):
         assert x.name == y.name == z.name
         assert np.array_equal(x.graph.w_out, y.graph.w_out)
@@ -44,8 +60,8 @@ def test_program_scenario_carries_the_same_deployment():
     describe the same links, flows and schedule (machine m's uplink and
     downlink mapped through the program's topology)."""
     cfg = dict(_cfg("storm-testbed-8"), n_scenarios=18)
-    for sc in deploy.testbed_corpus(cfg, BIG_SEED):
-        scen = deploy.program_scenario(sc)
+    for sc in TESTBED.corpus(cfg, BIG_SEED):
+        scen = TESTBED.program_scenario(sc)
         sim = scen.compile()
         ref = reference.testbed_arrays(sc.graph, sc.placement, sc.n_machines,
                                        sc.cap, sc.events, sc.diurnal)
@@ -87,7 +103,8 @@ def test_fat_tree_routes():
 
 
 def test_fattree_k16_1024_hosts_6144_links_1920_flows():
-    fab = deploy.fabric(_cfg("fattree-k16"), BIG_SEED)
+    fab = FABRIC.fabric(_cfg("fattree-k16"), BIG_SEED,
+                        _traffic("fattree.controller"))
     assert fab.R.shape == (1920, 6144)
     assert len(fab.tenants) == 128
     hosts = np.concatenate([h for _, h in fab.tenants])
@@ -99,9 +116,10 @@ def test_fattree_k16_1024_hosts_6144_links_1920_flows():
 
 def test_flow_states_are_simulated_and_seeded():
     cfg = _cfg("fattree-k16")
-    fab = deploy.fabric(cfg, BIG_SEED)
-    a = deploy.flow_states(cfg, fab, 6, 2)
-    b = deploy.flow_states(cfg, deploy.fabric(cfg, BIG_SEED), 6, 2)
+    tr = _traffic("fattree.controller", n_states=6, warm_intervals=2)
+    fab = FABRIC.fabric(cfg, BIG_SEED, tr)
+    a = FABRIC.flow_states(cfg, fab, tr)
+    b = FABRIC.flow_states(cfg, FABRIC.fabric(cfg, BIG_SEED, tr), tr)
     assert len(a) == 6 and all(len(s) == 5 for s in a)
     assert all(np.array_equal(x, y) for s, t in zip(a, b)
                for x, y in zip(s, t))
@@ -110,3 +128,74 @@ def test_flow_states_are_simulated_and_seeded():
     assert allv.max() <= 8.0 * float(cfg["qcap_mb"])
     assert len({b"".join(f.tobytes() for f in s) for s in a}) == 6
     assert all(s[2].sum() > 0 for s in a)
+
+
+def _corpus_digest(corpus) -> str:
+    h = hashlib.sha256()
+    for sc in corpus:
+        h.update(repr((sc.name, sc.n_machines, sc.cap, sc.events,
+                       sc.diurnal)).encode())
+        h.update(np.asarray(sc.placement, np.int64).tobytes())
+        h.update(np.asarray(sc.graph.w_out, np.float64).tobytes())
+    return h.hexdigest()
+
+
+def _fabric_digest(fab, states) -> str:
+    h = hashlib.sha256()
+    for a in (fab.R, fab.cap, fab.kind, *(x for st in states for x in st)):
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def test_deployments_read_what_they_read_before_the_move():
+    """Digests taken on the benchmark before the deployments moved into
+    ``bench/deployments/``: storm-testbed-8's corpus (names, placements,
+    links, events, cycles, flow weights) and fattree-k16's R, capacities,
+    kinds and 6 flow states, at one seed."""
+    corpus = TESTBED.corpus(_cfg("storm-testbed-8"), BIG_SEED)
+    assert _corpus_digest(corpus) == (
+        "25a33f8f99f517b4c757a19ad2037de4f836ff0953fde9754ed3961c6d3aca5d")
+    cfg = _cfg("fattree-k16")
+    tr = _traffic("fattree.controller", n_states=6, warm_intervals=2)
+    fab = FABRIC.fabric(cfg, BIG_SEED, tr)
+    assert _fabric_digest(fab, FABRIC.flow_states(cfg, fab, tr)) == (
+        "bb0ecb1673a67b345ea94cb25621d3e9ef62931c1661d6802ae53d88a4228f18")
+
+
+def test_testbed_controller_16_links_17_flows_64_states():
+    """The controller traffic's testbed: TT on 8 machines at 1.875 MB/s,
+    every flow across one uplink and one downlink; its flow states are
+    the reference's appaware simulation of that testbed, seeded, and
+    most of them differ."""
+    cell = _testbed_controller()
+    cfg, tr = cell.config, cell.traffic
+    fab = TESTBED.fabric(cfg, BIG_SEED, tr)
+    assert fab.R.shape == (17, 16) and fab.R.dtype == np.float32
+    assert fab.R.sum(1).tolist() == [2.0] * 17
+    assert fab.kind.tolist() == [0, 1] * 8
+    assert set(fab.cap.tolist()) == {1.875}
+    a = TESTBED.flow_states(cfg, fab, tr)
+    b = TESTBED.flow_states(cfg, TESTBED.fabric(cfg, BIG_SEED, tr), tr)
+    assert len(a) == 64 and all(len(s) == 5 for s in a)
+    assert all(np.array_equal(x, y) for s, t in zip(a, b)
+               for x, y in zip(s, t))
+    allv = np.concatenate([f for s in a for f in s])
+    assert allv.dtype == np.float32 and allv.min() >= 0.0
+    assert len({b"".join(f.tobytes() for f in s) for s in a}) >= 32
+    c = TESTBED.flow_states(cfg, TESTBED.fabric(cfg, 7, tr), tr)
+    assert not all(np.array_equal(x, y) for s, t in zip(a, c)
+                   for x, y in zip(s, t))
+
+
+@pytest.mark.parametrize("testbed", [
+    {"app": "linkedin_tags", "link_mb_s": 1.875},
+    {"app": "trending_topics", "link_mb_s": 3.0},
+])
+def test_testbed_controller_takes_only_what_the_configuration_states(
+        testbed):
+    """A controller traffic can name only a testbed that the
+    configuration, and so its source, states: one of its apps at one of
+    its capacities."""
+    cell = _testbed_controller()
+    with pytest.raises(ValueError, match="configuration's"):
+        TESTBED.fabric(cell.config, 7, dict(cell.traffic, testbed=testbed))

@@ -1,7 +1,8 @@
 """A run with its timed path broken underneath reports ``correct`` false:
 each cell is driven on the CPU at a small size by ``fault_run.py`` (the
 harness's look for a chip skipped, all else as on the chip), once sound
-and once for every fault the cell can have."""
+and once for every fault the cell can have (``faults/<kind>.py``); the
+cells of ``BENCHMARK.json`` and the candidates whose files are here."""
 import json
 import os
 import subprocess
@@ -13,18 +14,17 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
 from benchlib import spec  # noqa: E402
-
-_FAULTS = {"campaign": ("state_unchanged", "half_batch", "quarter_batch",
-                         "one_bucket", "one_chunk", "answer_altered"),
-           "controller": ("state_unchanged", "half_batch", "answer_altered")}
+from fault_run import benchmark, faults  # noqa: E402
 
 
 def _cases():
     out = []
-    for w in spec.load_benchmark()["workloads"]:
-        c = spec.resolve(w["name"])
+    bench = benchmark()
+    for w in bench["workloads"]:
+        c = spec.resolve(w["name"], bench=bench)
         out.append((w["name"], "none", True))
-        out += [(w["name"], f, False) for f in _FAULTS[c.traffic["kind"]]]
+        out += [(w["name"], f, False)
+                for f in faults(c.traffic["kind"]).FAULTS]
     return out
 
 

@@ -4,7 +4,9 @@ it)."""
 import numpy as np
 import pytest
 
-from benchlib import campaign, deploy, reference, spec
+from benchlib import reference, spec
+
+campaign = spec.load_loop("campaign")
 
 
 @pytest.mark.parametrize("policy", ["tcp", "appaware"])
@@ -15,18 +17,16 @@ def test_simulation_reference_matches_program(policy):
     few)."""
     from repro.streams import simulate
 
-    cfg = dict(spec.resolve("testbed.campaign-tcp").config, n_scenarios=18)
+    cell = spec.resolve("testbed.campaign-tcp")
+    dep = cell.deployment
+    cfg = dict(cell.config, n_scenarios=18)
     kw = campaign.settings(cfg, policy)
     gaps = []
-    for sc in deploy.testbed_corpus(cfg, 2**33 + 5):
-        sim = deploy.program_scenario(sc).compile()
+    for sc in dep.corpus(cfg, 2**33 + 5):
+        sim = dep.program_scenario(sc).compile()
         prog = simulate(sim, policy, seconds=kw["seconds"], dt=kw["dt"],
                         upd_every=kw["upd_every"], qcap=kw["qcap"]).metrics
-        ref = reference.simulate_ref(
-            reference.testbed_arrays(sc.graph, sc.placement, sc.n_machines,
-                                     sc.cap, sc.events, sc.diurnal),
-            policy, int(round(kw["seconds"] / kw["dt"])), kw["dt"],
-            kw["upd_every"], kw["qcap"], reference.Arith("exact"))
+        ref = dep.reference_row(sc, policy, kw)
         gaps.append(campaign.row_gap(prog[None], ref, kw["seconds"])[0])
     gaps = np.asarray(gaps)
     if policy == "tcp":
@@ -55,10 +55,12 @@ def test_allocate_reference_matches_program():
     from repro.core.allocator import OnlineAllocator
     from repro.core.flowstate import FlowState
 
-    cfg = spec.resolve("fattree.controller").config
-    fab = deploy.fabric(cfg, 11)
+    cell = spec.resolve("fattree.controller")
+    cfg, dep = cell.config, cell.deployment
+    tr = dict(cell.traffic, n_states=2, warm_intervals=2)
+    fab = dep.fabric(cfg, 11, tr)
     alloc = OnlineAllocator(fab.R, fab.cap, fab.kind, dt=5.0)
-    for st in deploy.flow_states(cfg, fab, 2, 2):
+    for st in dep.flow_states(cfg, fab, tr):
         x = np.asarray(alloc(FlowState(*st)), np.float64)
         r = reference.allocate_ref(fab.R, fab.cap, fab.kind, st, 5.0,
                                    reference.Arith("exact"))

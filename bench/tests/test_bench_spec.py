@@ -1,4 +1,5 @@
-"""Every cell of BENCHMARK.json resolves by name to its files, the file
+"""Every cell of BENCHMARK.json, and every candidate cell whose files are
+here (``fault_run.CANDIDATES``), resolves by name to its files, the file
 keeps to the shapes its format allows, and a configuration, traffic mix or
 per-layer metric added as a new file is found without editing any file
 that is there."""
@@ -10,23 +11,35 @@ import shutil
 import pytest
 
 from benchlib import spec
+from fault_run import benchmark
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 BENCH = spec.load_benchmark()
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def _loop_layers(cell: str) -> tuple:
+    """The layers the loop of ``cell``'s traffic kind declares."""
+    return spec.load_loop(spec.resolve(cell).traffic["kind"]).LAYERS
+
+
+WITH_CANDIDATES = benchmark()
+
+
+@pytest.mark.parametrize("cell",
+                         [w["name"] for w in WITH_CANDIDATES["workloads"]])
 def test_cell_resolves(cell):
-    c = spec.resolve(cell)
+    c = spec.resolve(cell, bench=WITH_CANDIDATES)
     assert c.chips in (1, 4)
-    assert c.traffic["kind"] in ("campaign", "controller")
+    loop = spec.load_loop(c.traffic["kind"])
+    assert callable(loop.run) and callable(loop.control)
     assert "limits" in c.traffic
     assert any(m["name"] == "setup_s" for m in c.end_to_end)
     assert len(c.end_to_end) >= 2 and c.per_layer
     moved = {m["name"] for m in c.end_to_end}
     for m in c.per_layer:
         assert m["moves"] in moved
+        assert m["layer"] in loop.LAYERS, (m["name"], m["layer"])
         assert callable(spec.load_reader(m["name"]))
 
 
@@ -53,9 +66,11 @@ def test_benchmark_file_shapes():
     for m in BENCH["end_to_end"]:
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
-    layers = {m["layer"] for m in BENCH["per_layer"]}
-    assert layers == {"campaign pipeline", "scan program", "device",
-                      "controller solve"}
+    cells = [w["name"] for w in BENCH["workloads"]]
+    layers = {c: _loop_layers(c) for c in cells}
+    for m in BENCH["per_layer"]:
+        for c in m.get("workloads", cells):
+            assert m["layer"] in layers[c], (m["name"], m["layer"], c)
     assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(
         1, len(BENCH["workloads"]) // 2)
 
@@ -96,3 +111,11 @@ def test_new_files_are_found(tmp_path):
     # the cells that were there resolve as before
     assert spec.resolve("testbed.campaign-tcp", str(root)).traffic == \
         spec.resolve("testbed.campaign-tcp").traffic
+
+
+@pytest.mark.parametrize("load", [spec.load_loop, spec.load_deployment])
+def test_missing_file_is_named(tmp_path, load):
+    """A traffic kind or deployment with no file of its own is an error
+    that names the file it looked for."""
+    with pytest.raises(FileNotFoundError, match="no_such_thing.py"):
+        load("no_such_thing", str(tmp_path))
