@@ -9,6 +9,14 @@ The program's spans: ``campaign.startup``, ``campaign.stage``,
 ``campaign.h2d``, ``campaign.wait_h2d``, ``campaign.dispatch`` and
 ``campaign.collect`` in ``FleetRunner.run_campaign`` (their seconds go to
 ``last_stats``), and ``allocator.launch`` in ``OnlineAllocator.__call__``.
+Beside them ``run_campaign`` counts rows: ``last_stats["rows_dispatched"]``
+(padded rows of every dispatch) and ``last_stats["rows_loaded"]`` (rows
+whose scenario has a source load schedule).
+
+The device's work carries ``jax.named_scope`` names in its op_name:
+``tick`` and ``maxmin`` in the campaign's scan, ``load`` around the
+evaluation of a run's source-load grid (``simulator._load_over``), and
+``per_link`` and ``backfill`` inside ``allocate``.
 """
 from __future__ import annotations
 

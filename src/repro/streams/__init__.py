@@ -5,7 +5,7 @@ from repro.streams.app import (  # noqa: F401
     Operator,
     StreamApp,
     parallelize,
-    source_sink_paths,
+    path_weights,
 )
 from repro.streams.faults import (  # noqa: F401
     FailureRecord,
@@ -22,6 +22,7 @@ from repro.streams.fleet import (  # noqa: F401
     simulate_many,
     stack_sims,
 )
+from repro.streams.load import LoadSchedule  # noqa: F401
 from repro.streams.placement import STRATEGIES, round_robin, packed, traffic_aware  # noqa: F401
 from repro.streams.scenarios import (  # noqa: F401
     Scenario,
@@ -48,6 +49,7 @@ from repro.streams.workloads import (  # noqa: F401
     WORKLOADS,
     linkedin_tags,
     motivation_chain,
+    nexmark_q5,
     trending_topics,
     trucking_iot,
 )

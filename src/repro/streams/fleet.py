@@ -325,6 +325,12 @@ class FleetShape:
     # 0 with never-activating intervals, so its per-tick gather returns
     # exactly its static routing matrix.
     n_route_states: int = 0
+    # source-load axes: sinusoids [S_g, I] and steps [E_g] (times) with
+    # [E_g, I] scales. Padded sinusoids have zero amplitude, padded steps
+    # never start and scale by 1, so a constant-load scenario in a loaded
+    # bucket generates exactly its own gen_rate.
+    n_load_sins: int = 0
+    n_load_steps: int = 0
 
     @classmethod
     def cover(cls, sims: Sequence[CompiledSim]) -> "FleetShape":
@@ -337,6 +343,8 @@ class FleetShape:
             n_sins=max(s.sin_amp.shape[0] for s in sims),
             n_events=max(s.ev_t0.shape[0] for s in sims),
             n_route_states=max(s.route_bank.shape[0] for s in sims),
+            n_load_sins=max(s.load_amp.shape[0] for s in sims),
+            n_load_steps=max(s.load_t0.shape[0] for s in sims),
         )
 
     def merge(self, other: "FleetShape") -> "FleetShape":
@@ -350,7 +358,9 @@ def _sim_shape(sim: CompiledSim) -> FleetShape:
         n_flows=sim.R.shape[0], n_links=sim.R.shape[1],
         n_insts=sim.M_in.shape[0], n_apps=sim.n_apps,
         n_sins=sim.sin_amp.shape[0], n_events=sim.ev_t0.shape[0],
-        n_route_states=sim.route_bank.shape[0])
+        n_route_states=sim.route_bank.shape[0],
+        n_load_sins=sim.load_amp.shape[0],
+        n_load_steps=sim.load_t0.shape[0])
 
 
 def _sim_content_sig(sim: CompiledSim) -> int:
@@ -409,6 +419,10 @@ def _flop_cost(shape: FleetShape, policy: str = "tcp") -> float:
         # pay this too (their base R rides bank slot 0), so the planner
         # weighs the mix like it does the schedule machinery.
         base += 2.0 * F * L + 4.0 * shape.n_route_states
+    if shape.n_load_sins > 0 or shape.n_load_steps > 0:
+        # source load: the [T, I] rate grid, evaluated once per run
+        # ([S_g + E_g] terms per instance and tick) and sliced per tick
+        base += 2.0 * I + 2.0 * (shape.n_load_sins + shape.n_load_steps) * I
     if policy in ("tcp", "appfair"):
         base += 3.0 * 2.0 * (F + 1.0) * F * 2.0 * L
     elif policy == "appaware":
@@ -529,12 +543,13 @@ def _pad1(a, n, value=0.0):
     return a if pad <= 0 else np.pad(a, (0, pad), constant_values=value)
 
 
-def _pad2(a, n0, n1):
+def _pad2(a, n0, n1, value=0.0):
     a = np.asarray(a)
     p0, p1 = n0 - a.shape[0], n1 - a.shape[1]
     if p0 <= 0 and p1 <= 0:
         return a
-    return np.pad(a, ((0, max(p0, 0)), (0, max(p1, 0))))
+    return np.pad(a, ((0, max(p0, 0)), (0, max(p1, 0))),
+                  constant_values=value)
 
 
 def _pad_route_fields(sim: CompiledSim, F: int, L: int,
@@ -574,6 +589,7 @@ def pad_sim(sim: CompiledSim, shape: FleetShape,
     F, L = shape.n_flows, shape.n_links
     I, A = shape.n_insts, shape.n_apps
     S, E = shape.n_sins, shape.n_events
+    SG, EG = shape.n_load_sins, shape.n_load_steps
     if sim.n_apps > A:
         raise ValueError(f"cannot pad n_apps {sim.n_apps} down to {A}")
     # the compile boundary already validates, but sims are mutable and may
@@ -582,10 +598,13 @@ def pad_sim(sim: CompiledSim, shape: FleetShape,
         "pad_sim",
         finite_nonneg=[("caps", sim.caps),
                        ("gen_rate", sim.gen_rate),
-                       ("ev_scale", sim.ev_scale)],
+                       ("ev_scale", sim.ev_scale),
+                       ("load_scale", sim.load_scale)],
         nonneg_inf_ok=[("proc_rate", sim.proc_rate),
                        ("ev_t0", sim.ev_t0),
-                       ("ev_t1", sim.ev_t1)])
+                       ("ev_t1", sim.ev_t1),
+                       ("load_t0", sim.load_t0),
+                       ("load_t1", sim.load_t1)])
     f = False
     route_bank, route_t, route_state = _pad_route_fields(
         sim, F, L, shape.n_route_states)
@@ -623,6 +642,12 @@ def pad_sim(sim: CompiledSim, shape: FleetShape,
         route_bank=route_bank,
         route_t=route_t,
         route_state=route_state,
+        load_amp=_pad2(sim.load_amp, SG, I),
+        load_omega=_pad2(sim.load_omega, SG, I),
+        load_phase=_pad2(sim.load_phase, SG, I),
+        load_t0=_pad1(sim.load_t0, EG, np.inf),
+        load_t1=_pad1(sim.load_t1, EG, np.inf),
+        load_scale=_pad2(sim.load_scale, EG, I, 1.0),
     )
 
 
@@ -640,7 +665,8 @@ def stack_sims(
     return stacked, shape
 
 
-# field -> (padded-dim axes, pad value); dims keyed into {F, L, I, S, E}.
+# field -> (padded-dim axes, pad value); dims keyed into
+# {F, L, I, S, E, SR, SG, EG}.
 # A staging row never slice-assigned from a real scenario keeps exactly
 # these pad values — which makes it an *inert scenario*: zero generation
 # and demand, huge-capacity INTERNAL links no solver binds on, never-
@@ -681,6 +707,13 @@ _FIELD_SPECS: dict[str, tuple[tuple[str, ...], float]] = {
     "route_bank": (("SR", "F", "L"), 0.0),
     "route_t": (("SR",), np.inf),
     "route_state": (("SR",), 0),
+    # source load: zero-amplitude sinusoids and never-starting steps
+    "load_amp": (("SG", "I"), 0.0),
+    "load_omega": (("SG", "I"), 0.0),
+    "load_phase": (("SG", "I"), 0.0),
+    "load_t0": (("EG",), np.inf),
+    "load_t1": (("EG",), np.inf),
+    "load_scale": (("EG", "I"), 1.0),
 }
 
 
@@ -920,7 +953,8 @@ class FleetRunner:
         dims = {"F": shape.n_flows, "L": shape.n_links,
                 "I": shape.n_insts,
                 "S": shape.n_sins, "E": shape.n_events,
-                "SR": shape.n_route_states}
+                "SR": shape.n_route_states,
+                "SG": shape.n_load_sins, "EG": shape.n_load_steps}
         for field, (axes, pad) in _FIELD_SPECS.items():
             first = np.asarray(getattr(sims[0], field))
             full = (rows,) + tuple(dims[a] for a in axes)
@@ -1249,7 +1283,8 @@ class FleetRunner:
         seconds of one named span, :mod:`repro.spans`), ``startup_s``
         (entry to the return of the first dispatch: the stretch in which
         the device has nothing to run), ``rows_dispatched`` (padded rows
-        of every dispatch, recovery re-runs included),
+        of every dispatch, recovery re-runs included), ``rows_loaded``
+        (the rows among them whose scenario has a source load schedule),
         ``overlap_fraction`` (share of *hideable* staging hidden behind
         in-flight compute; 1.0 when nothing was hideable — a single-chunk
         campaign has no compute to hide behind) and ``transfer_overlap``
@@ -1364,6 +1399,7 @@ class FleetRunner:
         # ---- resilience state (inert on the fault-free path) ----
         failures: list[FailureRecord] = []
         n_retries = n_recovered = n_dispatched = rows_dispatched = 0
+        rows_loaded = 0
         chunks_done = 0
         chunks_on: dict[str, int] = {}  # device -> pipeline chunks run there
         rec_col = metric_index("recovery_time_s")
@@ -1530,6 +1566,7 @@ class FleetRunner:
             subset. Staging goes into FRESH scratch buffers — never the
             rotating pipeline slots, which an in-flight (or abandoned)
             transfer may still alias."""
+            nonlocal rows_loaded
             shape = plan[bi][1]
             rows = cap_rows[bi]
             _fire("pack", j)
@@ -1545,6 +1582,7 @@ class FleetRunner:
             enf = np.zeros(rows, bool)
             for b, sim in enumerate(chunk):
                 enf[b] = sim.is_dynamic
+            rows_loaded += sum(sim.is_loaded for sim in chunk)
             _fire("transfer", j)
             pack, xfd, enfd = jax.device_put((stacked, xf, enf),
                                              stream_sh[s])
@@ -1715,6 +1753,7 @@ class FleetRunner:
                         enf = np.zeros(rows, bool)
                         for b, sim in enumerate(chunk):
                             enf[b] = sim.is_dynamic
+                        rows_loaded += sum(sim.is_loaded for sim in chunk)
                 except Exception as e:  # noqa: BLE001 — route to recovery
                     # pack failed before the slot advanced: nothing was
                     # submitted, the phase counter stays put, and the
@@ -1798,6 +1837,7 @@ class FleetRunner:
                 "peak_staged_rows": peak_rows,
                 "peak_staged_bytes": peak_bytes,
                 "rows_dispatched": rows_dispatched,
+                "rows_loaded": rows_loaded,
                 **tot,
                 "wall_s": wall_s,
                 "overlap_fraction": (hidden_stage_s / hideable_stage_s

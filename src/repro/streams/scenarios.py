@@ -52,6 +52,7 @@ from repro.net.topology import (
     link_failure_schedule,
 )
 from repro.streams.app import Edge, Grouping, InstanceGraph, Operator, StreamApp, parallelize
+from repro.streams.load import LoadSchedule
 from repro.streams.placement import round_robin
 from repro.streams.simulator import CompiledSim, compile_sim
 from repro.streams.workloads import (
@@ -71,10 +72,12 @@ class Scenario:
     placement: np.ndarray
     schedule: LinkSchedule | None = None   # in-run capacity dynamics
     reroute: bool = False                  # SDN rerouting around failures
+    load: LoadSchedule | None = None       # in-run source load
 
     def compile(self) -> CompiledSim:
         return compile_sim(self.graph, self.topo, self.placement,
-                           schedule=self.schedule, reroute=self.reroute)
+                           schedule=self.schedule, reroute=self.reroute,
+                           load=self.load)
 
 
 def compile_fleet(scenarios: list[Scenario]) -> list[CompiledSim]:

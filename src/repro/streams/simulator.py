@@ -29,6 +29,13 @@ rates are stale — that stale window is exactly the transient the paper's
 Fig. 5/12 regime is about). A sim compiled without a schedule (S = 0
 sinusoids, E = 0 events) skips every dynamic term *by shape* and runs the
 static path unchanged.
+
+**In-run source load:** a :class:`repro.streams.load.LoadSchedule`
+(SINE sinusoids, SQUARE steps) scales the sources' generation rates.
+``_load_over`` evaluates it once per run to a ``[T, I]`` rate grid that
+the scan streams beside the capacities; each tick reads its row in place
+of the constant ``gen_rate``. A sim without one (S_g = 0, E_g = 0) skips
+it by shape.
 """
 from __future__ import annotations
 
@@ -50,7 +57,8 @@ from repro.core.multiapp import (
 )
 from repro.core.tcp import maxmin_fused_step, maxmin_order_init
 from repro.net.topology import LinkSchedule, RouteSchedule, Topology
-from repro.streams.app import InstanceGraph, source_sink_paths
+from repro.streams.app import InstanceGraph, path_weights
+from repro.streams.load import LoadSchedule
 
 _EPS = 1e-9
 INTERNAL_RATE = 1e6  # MB/s: same-machine flows move at memory speed
@@ -91,6 +99,8 @@ def metric_index(name: str) -> int:
         "sin_amp", "sin_omega", "sin_phase",
         "ev_t0", "ev_t1", "ev_link", "ev_scale",
         "route_bank", "route_t", "route_state",
+        "load_amp", "load_omega", "load_phase",
+        "load_t0", "load_t1", "load_scale",
     ),
 )
 @dataclasses.dataclass
@@ -118,7 +128,8 @@ class CompiledSim:
     w_of_flow: Any       # [F] = w_out[src_of_flow[f], f] (the column's only
                          #      nonzero: each flow has one source instance)
     path_w: Any          # [F] per-flow latency weight = Σ_p paths[p, f]/P
-                         #     (the path-mean contraction, pre-collapsed so
+                         #     (app.path_weights: the path-mean
+                         #     contraction, pre-collapsed so
                          #     the scan never carries a [P, F] matvec; the
                          #     latency itself is a host-side dot — see
                          #     SimResult construction)
@@ -148,6 +159,14 @@ class CompiledSim:
     route_bank: Any      # [S_r, F, L] routing matrix per route state
     route_t: Any         # [S_r] interval start times (inf = padding)
     route_state: Any     # [S_r] int32 state index per interval
+    # source load schedule (see repro.streams.load.LoadSchedule); S_g = 0
+    # / E_g = 0 means constant generation, skipped by shape
+    load_amp: Any        # [S_g, I]
+    load_omega: Any      # [S_g, I]
+    load_phase: Any      # [S_g, I]
+    load_t0: Any         # [E_g]
+    load_t1: Any         # [E_g]
+    load_scale: Any      # [E_g, I]
 
     @property
     def program(self) -> LinkProgram:
@@ -172,6 +191,14 @@ class CompiledSim:
         index or gather from the bank, so static-routing runs are bitwise
         the pre-reroute path."""
         return self.route_bank.shape[0] > 0
+
+    @property
+    def is_loaded(self) -> bool:
+        """Whether a source load schedule is attached — a *shape*
+        predicate like :attr:`is_dynamic` (S_g > 0 sinusoids or E_g > 0
+        steps): a sim without one streams no rate grid and runs the
+        constant-load path bitwise."""
+        return self.load_amp.shape[0] > 0 or self.load_t0.shape[0] > 0
 
 
 def _validate_sim_inputs(where: str, *,
@@ -215,8 +242,11 @@ def compile_sim(
     n_apps: int = 1,
     schedule: LinkSchedule | None = None,
     reroute: "bool | RouteSchedule" = False,
+    load: LoadSchedule | None = None,
 ) -> CompiledSim:
-    """Compile one scenario. ``reroute=True`` derives a
+    """Compile one scenario. ``load`` schedules the sources' generation
+    rates in-run (only instances with ``gen_rate > 0`` may carry one).
+    ``reroute=True`` derives a
     :class:`~repro.net.topology.RouteSchedule` from ``schedule``'s events
     (the SDN controller reprograms routes around failed links mid-run); an
     explicit ``RouteSchedule`` is used as-is. A schedule whose events never
@@ -256,14 +286,13 @@ def compile_sim(
         if s > 0:
             p_in[sel] /= s
     droppable = np.array([edges[e].droppable for e in graph.edge_of_flow])
-    # collapse the [P, F] path masks to one per-flow weight vector: the
-    # latency estimate is linear in the per-flow waits (Σ_p Σ_f paths[p, f]
+    # one per-flow weight vector for the source-to-sink paths: the latency
+    # estimate is linear in the per-flow waits (Σ_p Σ_f paths[p, f]
     # · wait[f] / P), so the path axis contracts at compile time — the scan
     # outputs raw waits and the SimResult takes the dot on the host, which
     # keeps the estimate bitwise-independent of fleet padding (an XLA
     # matvec re-associates when the contraction length changes)
-    paths = source_sink_paths(graph)
-    path_w = paths.sum(0) / max(paths.shape[0], 1)
+    path_w = path_weights(graph)
     app_of_inst = (
         np.zeros(graph.n_instances, np.int32) if app_of_inst is None else app_of_inst
     )
@@ -281,14 +310,35 @@ def compile_sim(
         raise ValueError(
             f"schedule event links {ev_link} out of range for "
             f"{topo.n_links} links")
+    if load is None:
+        load = LoadSchedule.empty(graph.n_instances)
+    elif load.n_instances != graph.n_instances:
+        raise ValueError(
+            f"load schedule covers {load.n_instances} instances, graph has "
+            f"{graph.n_instances}")
+    loaded = (np.any(np.asarray(load.step_scale) != 1, axis=0)
+              | np.any(np.asarray(load.sin_amp) != 0, axis=0))
+    not_src = np.flatnonzero(loaded & ~(np.asarray(graph.gen_rate) > 0))
+    if not_src.size:
+        raise ValueError(
+            f"load schedule scales instances {not_src}, which generate "
+            f"nothing (gen_rate 0)")
     _validate_sim_inputs(
         "compile_sim",
         finite_nonneg=[("capacities", topo.capacities),
                        ("gen_rate", graph.gen_rate),
-                       ("ev_scale", schedule.ev_scale)],
+                       ("ev_scale", schedule.ev_scale),
+                       ("load_scale", load.step_scale)],
         nonneg_inf_ok=[("proc_rate", graph.proc_rate),
                        ("ev_t0", schedule.ev_t0),
-                       ("ev_t1", schedule.ev_t1)])
+                       ("ev_t1", schedule.ev_t1),
+                       ("load_t0", load.step_t0),
+                       ("load_t1", load.step_t1)])
+    for field, a in (("load_amp", load.sin_amp),
+                     ("load_omega", load.sin_omega),
+                     ("load_phase", load.sin_phase)):
+        if not np.all(np.isfinite(a)):
+            raise ValueError(f"compile_sim: {field} must be finite")
     F, L = len(flows), topo.n_links
     if reroute is True:
         reroute = RouteSchedule.from_events(topo, flows, schedule)
@@ -352,6 +402,12 @@ def compile_sim(
         route_bank=f32(route_bank),
         route_t=f32(route_t),
         route_state=jnp.asarray(route_state, jnp.int32),
+        load_amp=f32(load.sin_amp),
+        load_omega=f32(load.sin_omega),
+        load_phase=f32(load.sin_phase),
+        load_t0=f32(load.step_t0),
+        load_t1=f32(load.step_t1),
+        load_scale=f32(load.step_scale),
     )
 
 
@@ -396,6 +452,34 @@ def _caps_over(sim: CompiledSim, ts: jnp.ndarray) -> jnp.ndarray:
         scale = jax.vmap(lambda m: ones.at[idx].multiply(m))(mult)
         caps = caps * scale
     return jnp.maximum(caps, 0.0)
+
+
+def _load_over(sim: CompiledSim, ts: jnp.ndarray) -> jnp.ndarray:
+    """Evaluate the source load schedule on a tick grid: the generation
+    rate of every instance at every tick, [T, I].
+
+    Computed once per run outside the scan, like ``_caps_over``, and
+    streamed as ``xs``: no trig per tick. Zero-amplitude sinusoids and
+    never-starting steps (fleet padding) scale by exactly 1.0, so a
+    constant-load sim padded into a loaded bucket generates bitwise its
+    own ``gen_rate``.
+    """
+    with jax.named_scope("load"):
+        I = sim.gen_rate.shape[0]
+        gen = jnp.broadcast_to(sim.gen_rate[None, :], (ts.shape[0], I))
+        if sim.load_amp.shape[0]:
+            wave = jnp.sum(
+                sim.load_amp[None] * jnp.sin(
+                    sim.load_omega[None] * ts[:, None, None]
+                    + sim.load_phase[None]), axis=1)          # [T, I]
+            gen = gen * (1.0 + wave)
+        if sim.load_t0.shape[0]:
+            active = (ts[:, None] >= sim.load_t0[None]) & (
+                ts[:, None] < sim.load_t1[None])              # [T, E]
+            gen = gen * jnp.prod(
+                jnp.where(active[:, :, None], sim.load_scale[None], 1.0),
+                axis=1)                                       # [T, I]
+        return jnp.maximum(gen, 0.0)
 
 
 def _metrics_epilogue(sink, wait, load, caps_grid, path_w, dt: float,
@@ -792,7 +876,8 @@ def _run(sim: CompiledSim, policy: str, n_ticks: int, dt: float,
     # before in-run dynamics existed
     dynamic = sim.is_dynamic
     rerouting = sim.is_rerouting
-    if dynamic or rerouting:
+    loaded = sim.is_loaded
+    if dynamic or rerouting or loaded:
         ts = jnp.arange(n_ticks, dtype=jnp.float32) * dt
     if dynamic:
         caps_sched = _caps_over(sim, ts)              # [T, L]
@@ -802,6 +887,9 @@ def _run(sim: CompiledSim, policy: str, n_ticks: int, dt: float,
     # active state's routing matrix from the precompiled bank — mid-run
     # rerouting without recompilation or lax.cond
     states_seq = _route_states_over(sim, ts) if rerouting else None
+    # per-tick generation rates (S_g > 0 or E_g > 0 only): each tick runs
+    # on a copy of the sim whose gen_rate is that tick's row
+    gen_seq = _load_over(sim, ts) if loaded else None
 
     no_rebuild = jnp.zeros((), bool)
 
@@ -833,9 +921,11 @@ def _run(sim: CompiledSim, policy: str, n_ticks: int, dt: float,
         return x, oc, no_rebuild
 
     def body(carry, xs):
-        tick, caps_t, state_t = xs
+        tick, caps_t, state_t, gen_t = xs
         (Qs, Qr, B, x, v_acc, ls, lr, prod_rate, drain_ewma, mu,
          mu_acc, oc) = carry
+        sim_t = (sim if gen_t is None
+                 else dataclasses.replace(sim, gen_rate=gen_t))
         caps_upd = sim.caps if caps_t is None else caps_t
         # active routing matrix: one [F, L] bank gather per tick. The
         # policies re-solve against R(t_upd) at their update ticks, so
@@ -866,13 +956,13 @@ def _run(sim: CompiledSim, policy: str, n_ticks: int, dt: float,
                 do_upd, updated, kept, None)
 
         Qs1, Qr1, transfer, drain, (sink, sink_app, wait, load) = _tick(
-            sim, Qs, Qr, x, dt, qcap, caps_t=caps_t, enforce=enforce,
+            sim_t, Qs, Qr, x, dt, qcap, caps_t=caps_t, enforce=enforce,
             R_t=R_t)
         # per-policy carry pieces are gated *statically*: a policy that
         # never reads prod_rate/B/mu_acc doesn't pay their per-tick ops
         if policy == "tcp":
             t_in = jnp.matmul(sim.M_in, transfer, precision=_HIGHEST)
-            out_i = sim.selectivity * t_in + sim.gen_rate * dt
+            out_i = sim.selectivity * t_in + sim_t.gen_rate * dt
             prod_rate = out_i[sim.src_of_flow] * sim.w_of_flow / dt
             drain_ewma = 0.5 * drain_ewma + 0.5 * drain
         if policy == "appaware":
@@ -892,9 +982,10 @@ def _run(sim: CompiledSim, policy: str, n_ticks: int, dt: float,
     # did before the order cache existed
     oc0 = maxmin_order_init(F) if policy == "tcp" else ()
     carry0 = (z, z, z, z, z, z, z, z, z, mu0, mu0, oc0)
-    # None is an empty pytree leaf: static sims stream no capacity xs and
-    # static-routing sims stream no state index
-    xs = (jnp.arange(n_ticks), caps_sched if dynamic else None, states_seq)
+    # None is an empty pytree leaf: static sims stream no capacity xs,
+    # static-routing sims no state index, constant-load sims no rate grid
+    xs = (jnp.arange(n_ticks), caps_sched if dynamic else None, states_seq,
+          gen_seq)
     _, ys = jax.lax.scan(body, carry0, xs)
     if not with_metrics:
         return (*ys, caps_sched)

@@ -125,9 +125,51 @@ def motivation_chain() -> StreamApp:
     )
 
 
+def nexmark_q5(events_per_s: float = 25_000.0,
+               parallelism: int = 8) -> StreamApp:
+    """NEXmark Query 5, "hot items" (Apache Beam's Nexmark suite): count
+    the bids of every auction over sliding windows of 10 s every 5 s and
+    report the auction with the most bids.
+
+    Beam's ``NexmarkConfiguration``: events person/auction/bid 1/3/46 of
+    mean sizes 200/500/100 B, so 126 B an event and 73% of the bytes are
+    bids. ``events`` generates ``events_per_s``; ``bids`` keeps the bids;
+    ``count`` is key-grouped by auction and emits one (auction, count)
+    record per auction per slide (selectivity about 1e-3); ``max`` keeps
+    one of the ~100 in-flight auctions' records (Beam's
+    ``numInFlightAuctions``). The hot auction (``hotAuctionRatio`` 2)
+    moves to each new auction, many times a tick at these rates, so every
+    count instance's share over a tick is even: key skew 0. ``max`` reads
+    a few hundred bytes a slide and is not a lock-step join. Every
+    operator processes 100 MB/s, so the network binds, not the CPU."""
+    event_bytes = (1 * 200 + 3 * 500 + 46 * 100) / 50     # 126 B
+    bid_share = 46 * 100 / (50 * event_bytes)              # 0.73
+    return StreamApp(
+        name="nexmark_q5",
+        operators=[
+            Operator("events", parallelism, proc_rate=100.0,
+                     gen_rate=events_per_s * event_bytes / 1e6),
+            Operator("bids", parallelism, proc_rate=100.0,
+                     selectivity=bid_share),
+            Operator("count", parallelism, proc_rate=100.0,
+                     selectivity=1e-3),
+            Operator("max", 1, proc_rate=100.0, selectivity=1e-2),
+            Operator("sink", 1, proc_rate=100.0, selectivity=0.0),
+        ],
+        edges=[
+            Edge("events", "bids", Grouping.SHUFFLE),
+            Edge("bids", "count", Grouping.KEY, key_skew=0.0),
+            Edge("count", "max", Grouping.GLOBAL),
+            Edge("max", "sink", Grouping.GLOBAL),
+        ],
+        tuples_per_mb=1e6 / event_bytes,
+    )
+
+
 WORKLOADS = {
     "TT": trending_topics,
     "TI": trucking_iot,
     "tags": linkedin_tags,
     "motivation": motivation_chain,
+    "Q5": nexmark_q5,
 }

@@ -115,3 +115,35 @@ def test_tcp_chunk_names_its_scopes(campaign_texts):
     text = campaign_texts("tcp")
     assert "/maxmin/" in text
     assert "/tick/" in text
+
+
+def test_loaded_appaware_chunk_compiles(one_chip):
+    """NEXmark Q5 under SINE and SQUARE input (F = 137, I = 26, the
+    [T, I] rate grid of one sinusoid and 11 steps) in a 64-row appaware
+    chunk over 1200 ticks: the load grid keeps its scope."""
+    from repro.net.topology import big_switch
+    from repro.streams import LoadSchedule, compile_sim, nexmark_q5, parallelize
+
+    g = parallelize(nexmark_q5(), seed=0)
+    src = np.flatnonzero(g.gen_rate > 0)
+    topo, place = big_switch(8, 1.25), np.arange(g.n_instances) % 8
+    sine = LoadSchedule.empty(g.n_instances).with_sine(120.0, 1.0, 4.0, src)
+    square = LoadSchedule.empty(g.n_instances)
+    for k in range(11):
+        square = square.with_steps(60.0 * k, 60.0 * k + 30.0, 4.0, src)
+    sims = [compile_sim(g, topo, place, load=ld) for ld in (sine, square)]
+    runner = FleetRunner(max_buckets=1, tick_overhead=TICK_OVERHEAD_FLOPS_CPU)
+    idxs, shape = runner.plan(sims, "appaware")[0]
+    assert (shape.n_flows, shape.n_load_sins, shape.n_load_steps) == (
+        137, 1, 11)
+    leaves = runner._fill_bucket({}, sims, shape, CHUNK_ROWS)
+    pack = CompiledSim(tuples_per_mb=1.0, n_apps=shape.n_apps,
+                       **{k: _spec(v.shape, v.dtype, one_chip)
+                          for k, v in leaves.items()})
+    fn = runner._executable(("tpu", "q5"), "appaware", N_TICKS, 0.5,
+                            resolve_upd_every("appaware", 0.5, None), 0.5, 8,
+                            "sort")
+    text = fn.lower((pack,), (None,), (_spec((CHUNK_ROWS,), np.bool_,
+                                             one_chip),),
+                    _spec((), jnp.float32, one_chip)).compile().as_text()
+    assert "/load/" in text and "while" in text
