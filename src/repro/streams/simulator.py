@@ -564,6 +564,20 @@ def _metrics_epilogue(sink, wait, load, caps_grid, path_w, dt: float,
 # --------------------------------------------------------------------------
 # one simulation tick (shared by all policies)
 # --------------------------------------------------------------------------
+def _pick(v, idx):
+    """``v[idx]``, bit for bit, for ``0 <= idx < len(v)``: a one-hot
+    select-and-sum over ``v``'s axis, where the gather it replaces lowers
+    to per-element fetches under vmap on the TPU. It sums the values' bit
+    patterns as integers (one pattern plus zeros), so a subnormal or a
+    negative zero survives backends that flush subnormals in float adds.
+    """
+    hit = idx[None, :] == jnp.arange(v.shape[0], dtype=idx.dtype)[:, None]
+    bits = jax.lax.bitcast_convert_type(v, jnp.int32)               # [I]
+    picked = jnp.sum(jnp.where(hit, bits[:, None], 0), axis=0,
+                     dtype=jnp.int32)                               # [F]
+    return jax.lax.bitcast_convert_type(picked, v.dtype)
+
+
 def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
           R_t=None):
     """One fluid step against the *current* link capacities ``caps_t``.
@@ -573,10 +587,13 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
     back half of the original chain collapses algebraically —
     ``M_in @ (consume·stall[dst]) = (M_in @ consume)·stall`` and
     ``w_out.T @ v = v[src]·w_of_flow`` — replacing two of the per-tick
-    [I, F] matmuls with O(F) gathers. The remaining contractions stay as
-    matmuls / masked reductions on purpose: under the fleet engine's vmap
-    they lower to batched GEMMs and reduces, where segment/scatter forms
-    would serialize on CPU backends.
+    [I, F] matmuls with per-flow picks ``v[dst]`` / ``v[src]``. Each pick
+    is a one-hot select-and-sum over the instance axis (``_pick``), not
+    an index gather: under the fleet engine's vmap the TPU lowers a
+    batched gather to per-element fetches, while a masked [I, F] reduce
+    is vector work. The remaining contractions stay as matmuls / masked
+    reductions on purpose: under vmap they lower to batched GEMMs and
+    reduces, where segment/scatter forms would serialize on CPU backends.
 
     ``enforce`` gates the per-tick capacity enforcement *per scenario*
     (a python bool for standalone sims, a traced scalar under the fleet
@@ -632,12 +649,12 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
         join_amt = jnp.min(masked, axis=1)                           # [I]
         join_amt = jnp.where(jnp.isfinite(join_amt), join_amt, 0.0)
         join_amt = jnp.minimum(join_amt, sim.proc_rate * dt)
-        consume_join = join_amt[dst] * sim.p_in                      # [F]
+        consume_join = _pick(join_amt, dst) * sim.p_in               # [F]
 
         total_in = jnp.matmul(sim.M_in, Qr, precision=_HIGHEST)      # [I]
         amt = jnp.minimum(total_in, sim.proc_rate * dt)
         frac = amt / jnp.maximum(total_in, _EPS)
-        consume_any = Qr * frac[dst]
+        consume_any = Qr * _pick(frac, dst)
 
         consume = jnp.where(sim.join_dst, consume_join, consume_any)
         consume = jnp.minimum(consume, Qr)
@@ -646,7 +663,7 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
         # whose outgoing queue is full stalls its processing / generation
         in_i = jnp.matmul(sim.M_in, consume, precision=_HIGHEST)     # [I]
         out_i = sim.selectivity * in_i + sim.gen_rate * dt
-        prod = out_i[src] * sim.w_of_flow                            # [F]
+        prod = _pick(out_i, src) * sim.w_of_flow                     # [F]
         space = jnp.maximum(qcap - Qs, 0.0)
         scale_f = jnp.clip(space / jnp.maximum(prod, _EPS), 0.0, 1.0)
         # droppable (latest-value) streams never backpressure upstream: the app
@@ -656,14 +673,14 @@ def _tick(sim: CompiledSim, Qs, Qr, x, dt, qcap, caps_t=None, enforce=True,
         stall_i = jnp.min(stalled, axis=1)                           # [I]
         stall_i = jnp.where(jnp.isfinite(stall_i), stall_i, 1.0)
 
-        consume = consume * stall_i[dst]
+        consume = consume * _pick(stall_i, dst)
         Qr = Qr - consume
         # stale-data discard: droppable join inputs keep only a small working
         # window; bytes beyond it were carried by the network for nothing.
         Qr = jnp.where(sim.droppable, jnp.minimum(Qr, 0.5), Qr)
         in_i = in_i * stall_i        # = M_in @ (consume·stall[dst]), fused
         out_i = sim.selectivity * in_i + sim.gen_rate * dt * stall_i
-        Qs = Qs + out_i[src] * sim.w_of_flow   # = w_out.T @ out_i, fused
+        Qs = Qs + _pick(out_i, src) * sim.w_of_flow  # = w_out.T @ out_i
         # latest-value send queues hold only the freshest working window
         Qs = jnp.where(sim.droppable, jnp.minimum(Qs, 0.5), Qs)
 
@@ -961,10 +978,12 @@ def _run(sim: CompiledSim, policy: str, n_ticks: int, dt: float,
         # per-policy carry pieces are gated *statically*: a policy that
         # never reads prod_rate/B/mu_acc doesn't pay their per-tick ops
         if policy == "tcp":
-            t_in = jnp.matmul(sim.M_in, transfer, precision=_HIGHEST)
-            out_i = sim.selectivity * t_in + sim_t.gen_rate * dt
-            prod_rate = out_i[sim.src_of_flow] * sim.w_of_flow / dt
-            drain_ewma = 0.5 * drain_ewma + 0.5 * drain
+            with jax.named_scope("feedback"):
+                t_in = jnp.matmul(sim.M_in, transfer, precision=_HIGHEST)
+                out_i = sim.selectivity * t_in + sim_t.gen_rate * dt
+                prod_rate = (_pick(out_i, sim.src_of_flow) * sim.w_of_flow
+                             / dt)
+                drain_ewma = 0.5 * drain_ewma + 0.5 * drain
         if policy == "appaware":
             B = jnp.clip(B + transfer - drain * dt, 0.0, 8.0 * qcap)
             v_acc = v_acc + transfer

@@ -117,10 +117,11 @@ def test_tcp_chunk_names_its_scopes(campaign_texts):
     assert "/tick/" in text
 
 
-def test_loaded_appaware_chunk_compiles(one_chip):
-    """NEXmark Q5 under SINE and SQUARE input (F = 137, I = 26, the
-    [T, I] rate grid of one sinusoid and 11 steps) in a 64-row appaware
-    chunk over 1200 ticks: the load grid keeps its scope."""
+@pytest.fixture(scope="module")
+def q5_appaware_text(one_chip):
+    """Compiled HLO of NEXmark Q5 under SINE and SQUARE input (F = 137,
+    I = 26, the [T, I] rate grid of one sinusoid and 11 steps) in a
+    64-row appaware chunk over 1200 ticks."""
     from repro.net.topology import big_switch
     from repro.streams import LoadSchedule, compile_sim, nexmark_q5, parallelize
 
@@ -143,7 +144,26 @@ def test_loaded_appaware_chunk_compiles(one_chip):
     fn = runner._executable(("tpu", "q5"), "appaware", N_TICKS, 0.5,
                             resolve_upd_every("appaware", 0.5, None), 0.5, 8,
                             "sort")
-    text = fn.lower((pack,), (None,), (_spec((CHUNK_ROWS,), np.bool_,
+    return fn.lower((pack,), (None,), (_spec((CHUNK_ROWS,), np.bool_,
                                              one_chip),),
                     _spec((), jnp.float32, one_chip)).compile().as_text()
-    assert "/load/" in text and "while" in text
+
+
+def test_loaded_appaware_chunk_compiles(q5_appaware_text):
+    """The Q5 chunk compiles and the load grid keeps its scope."""
+    assert "/load/" in q5_appaware_text and "while" in q5_appaware_text
+
+
+@pytest.mark.parametrize("chunk", ["tcp", "q5-appaware"])
+def test_tick_reads_flows_without_gathers(campaign_texts, q5_appaware_text,
+                                          chunk):
+    # under vmap the chip fetches a batched gather one element at a time;
+    # the per-flow picks of the tick and of tcp's rate feedback are one-hot
+    # select-and-sums (_pick)
+    text = q5_appaware_text if chunk == "q5-appaware" else campaign_texts(
+        "tcp")
+    scopes = ("/tick/", "/feedback/") if chunk == "tcp" else ("/tick/",)
+    assert all(s in text for s in scopes)
+    gathers = [ln.strip()[:160] for ln in text.splitlines()
+               if " gather(" in ln and any(s in ln for s in scopes)]
+    assert not gathers, gathers
